@@ -73,7 +73,8 @@ def run(quick: bool = False) -> None:
         row("paged_engine/sharded_step", 0.0,
             f"skipped;devices={ndev};need>=2")
     for d, m in mesh_shapes:
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((d, m), ("data", "model"))
         sharded = PagedRealtimeEngine(cfg, params, slots=slots,
                                       page_size=16, pages_per_seq=16,
                                       mesh=mesh)

@@ -61,8 +61,7 @@ def compressed_psum(grads, mesh, axis: str, errors=None):
                    * scale[:, None]).reshape(-1)[:x.size].reshape(shape)
             return mean, (x - deq)[None]
 
-        from repro.compat import shard_map
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, *([None] * len(shape))),) * 2,
             out_specs=(P(*([None] * len(shape))),

@@ -47,19 +47,23 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.paged_attention import paged_attention, \
     paged_prefill_attention
+from repro.launch.mesh import auto_axes
 
 
 class PagedKVLayout:
-    """How one engine's page store [L, P+1, page, Hkv, hd] shards."""
+    """How one engine's page store [L, P+1, page, Hkv*hd] shards.
+
+    The mesh is taken with Auto axes (``launch.mesh.auto_axes``): the
+    engine moves pages with plain ``device_put`` and functional updates
+    outside any mesh context, which Explicit axes refuse."""
 
     def __init__(self, cfg, mesh, page_size: int):
         assert "model" in mesh.axis_names, mesh.axis_names
         self.cfg = cfg
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh)
         self.page_size = page_size
         self.M = int(mesh.shape["model"])
         if cfg.num_kv_heads % self.M == 0:
@@ -75,8 +79,10 @@ class PagedKVLayout:
 
     # ------------------------------------------------------------ specs
     def page_pspec(self, *, with_layers: bool = True) -> P:
-        """PartitionSpec for [L, P+1, page, Hkv, hd] (or the 4D
-        kernel-level [P, page, Hkv, hd] with ``with_layers=False``)."""
+        """PartitionSpec for [L, P+1, page, Hkv*hd] (or the 3D
+        kernel-level [P, page, Hkv*hd] with ``with_layers=False``).
+        Heads shard the merged lane dim: each shard's slice is its
+        whole heads."""
         lead = (None,) if with_layers else ()
         if self.kind == "heads":
             return P(*lead, None, None, "model")
@@ -93,8 +99,8 @@ class PagedKVLayout:
         return NamedSharding(self.mesh, P())
 
     def stage_host_chunk(self, host_chunk):
-        """Stage one transfer-engine reload chunk ([n, 2, L, page, Hkv,
-        hd] host stack) onto the mesh. The chunk is replicated — it
+        """Stage one transfer-engine reload chunk ([n, 2, L, page,
+        Hkv*hd] host stack) onto the mesh. The chunk is replicated — it
         indexes whole logical pages, and the follow-up page-store
         scatter plus the engine's placement re-commit
         (``_place_pages``) land it on the layout's exact sharding. The
@@ -108,15 +114,15 @@ class PagedKVLayout:
         """Per-shard page write of one token per batch row.
 
         Runs *inside* shard_map: ``kc``/``vc`` are local shards
-        [P+1, page_local, Hkv_local, hd]; ``k``/``v`` [B, Hkv, hd] are
+        [P+1, page_local, (Hkv*hd)_local]; ``k``/``v`` [B, Hkv*hd] are
         the full (replicated) projections; ``write_page``/``write_slot``
         [B] i32 are global coordinates.
         """
         if self.kind == "heads":
             idx = jax.lax.axis_index("model")
-            hloc = kc.shape[2]
-            k = jax.lax.dynamic_slice_in_dim(k, idx * hloc, hloc, axis=1)
-            v = jax.lax.dynamic_slice_in_dim(v, idx * hloc, hloc, axis=1)
+            w = kc.shape[2]
+            k = jax.lax.dynamic_slice_in_dim(k, idx * w, w, axis=1)
+            v = jax.lax.dynamic_slice_in_dim(v, idx * w, w, axis=1)
             return (kc.at[write_page, write_slot].set(k),
                     vc.at[write_page, write_slot].set(v))
         if self.kind == "slots":
@@ -124,7 +130,7 @@ class PagedKVLayout:
             psl = kc.shape[1]
             own = (write_slot // psl) == idx
             loc = write_slot % psl
-            keep = own[:, None, None]
+            keep = own[:, None]
             k = jnp.where(keep, k, kc[write_page, loc])
             v = jnp.where(keep, v, vc[write_page, loc])
             return kc.at[write_page, loc].set(k), vc.at[write_page, loc].set(v)
@@ -137,7 +143,7 @@ class PagedKVLayout:
 
         Runs *inside* shard_map: ``q`` [B, Hq, D] is the full
         (replicated) query; ``kc``/``vc`` are local page shards
-        [P+1, page_local, Hkv_local, hd]. Returns the full [B, Hq, D]
+        [P+1, page_local, (Hkv*hd)_local]. Returns the full [B, Hq, D]
         attention output, identical on every shard.
         """
         if self.kind == "heads":
@@ -175,15 +181,15 @@ class PagedKVLayout:
         (DESIGN.md §11).
 
         Runs *inside* shard_map: ``kc``/``vc`` are local shards
-        [P+1, page_local, Hkv_local, hd]; ``k``/``v`` [B, Q, Hkv, hd]
+        [P+1, page_local, (Hkv*hd)_local]; ``k``/``v`` [B, Q, Hkv*hd]
         are the full (replicated) projections; ``write_pages``/
         ``write_slots`` [B, Q] i32 are global coordinates.
         """
         if self.kind == "heads":
             idx = jax.lax.axis_index("model")
-            hloc = kc.shape[2]
-            k = jax.lax.dynamic_slice_in_dim(k, idx * hloc, hloc, axis=2)
-            v = jax.lax.dynamic_slice_in_dim(v, idx * hloc, hloc, axis=2)
+            w = kc.shape[2]
+            k = jax.lax.dynamic_slice_in_dim(k, idx * w, w, axis=2)
+            v = jax.lax.dynamic_slice_in_dim(v, idx * w, w, axis=2)
             return (kc.at[write_pages, write_slots].set(k),
                     vc.at[write_pages, write_slots].set(v))
         if self.kind == "slots":
@@ -251,15 +257,18 @@ def sharded_paged_attention(layout: PagedKVLayout, q, k_pages, v_pages,
                             block_tables, seq_lens, *,
                             interpret: bool = False):
     """Global-view sharded paged attention: q [B, Hq, D] and
-    block_tables/seq_lens replicated; k_pages/v_pages [P, page, Hkv, D]
-    sharded per the layout. Drop-in equal to ``paged_attention``."""
+    block_tables/seq_lens replicated; k_pages/v_pages [P, page, Hkv*D]
+    (or the [P, page, Hkv, D] view) sharded per the layout. Drop-in
+    equal to ``paged_attention``."""
     spec = layout.page_pspec(with_layers=False)
     rep = P()
 
     def body(q, kp, vp, bt, sl):
         return layout.attend(q, kp, vp, bt, sl, interpret=interpret)
 
-    f = shard_map(body, mesh=layout.mesh,
+    k_pages = k_pages.reshape(*k_pages.shape[:2], -1)
+    v_pages = v_pages.reshape(*v_pages.shape[:2], -1)
+    f = jax.shard_map(body, mesh=layout.mesh,
                   in_specs=(rep, spec, spec, rep, rep), out_specs=rep,
                   check_vma=False)
     return f(q, k_pages, v_pages, block_tables, seq_lens)
@@ -288,12 +297,12 @@ def sharded_flash_prefill(layout: PagedKVLayout, q, k, v, *,
     Hq, Hkv = q.shape[1], k.shape[1]
     if Hq % layout.M == 0 and Hkv % layout.M == 0:
         spec = P(None, "model")
-        f = shard_map(kernel, mesh=layout.mesh,
+        f = jax.shard_map(kernel, mesh=layout.mesh,
                       in_specs=(spec, spec, spec), out_specs=spec,
                       check_vma=False)
     else:
         rep = P()
-        f = shard_map(kernel, mesh=layout.mesh,
+        f = jax.shard_map(kernel, mesh=layout.mesh,
                       in_specs=(rep, rep, rep), out_specs=rep,
                       check_vma=False)
     return f(q, k, v)
@@ -313,7 +322,7 @@ def make_sharded_step(cfg, layout: PagedKVLayout, *,
                              plane=layout)
     spec = layout.page_pspec(with_layers=True)
     rep = P()
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=layout.mesh,
         in_specs=(rep, rep, rep, spec, spec, rep, rep, rep, rep),
         out_specs=(rep, spec, spec),
@@ -334,7 +343,7 @@ def make_sharded_fused_step(cfg, layout: PagedKVLayout, *,
                              plane=layout)
     spec = layout.page_pspec(with_layers=True)
     rep = P()
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=layout.mesh,
         in_specs=(rep, rep, rep, spec, spec, rep, rep, rep, rep, rep),
         out_specs=(rep, spec, spec),
@@ -356,7 +365,7 @@ def make_sharded_spec_step(cfg, layout: PagedKVLayout, *,
                              plane=layout, spec=True)
     spec = layout.page_pspec(with_layers=True)
     rep = P()
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=layout.mesh,
         in_specs=(rep, rep, rep, spec, spec, rep, rep, rep, rep, rep),
         out_specs=(rep, rep, spec, spec),

@@ -24,6 +24,8 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import auto_axes
+
 FSDP_BYTES = 4e9       # per-chip weight budget before FSDP kicks in
 # (4 GB: with bf16 params + fp32 Adafactor master at rest, a non-FSDP
 # layout already costs 3x this per chip — phi3.5-moe at 5.25 GB/chip
@@ -43,7 +45,7 @@ class ShardingRules:
     def __init__(self, cfg, mesh, *, mode: str = "train",
                  fsdp: Optional[bool] = None, expert_tp: bool = False):
         self.cfg = cfg
-        self.mesh = mesh
+        self.mesh = mesh = auto_axes(mesh)
         self.mode = mode
         self.expert_tp = expert_tp
         self.data_axes = tuple(n for n in mesh.axis_names if n != "model")

@@ -1,3 +1,2 @@
-from repro.kernels.ops import (  # noqa: F401
-    flash_prefill_op, paged_attention_op, ssd_scan_op,
-)
+"""Pallas kernels (paged attention, flash prefill, SSD scan) and their
+pure-jnp oracles in ``ref``."""

@@ -43,12 +43,13 @@ def flash_prefill_ref(q, k, v, *, causal: bool = True, window=None,
 def paged_attention_ref(q, k_pages, v_pages, block_tables, seq_lens):
     """Decode attention over paged KV.
 
-    q [B, Hq, D]; k_pages/v_pages [P, page, Hkv, D];
-    block_tables [B, pages_per_seq] int32; seq_lens [B] int32.
+    q [B, Hq, D]; k_pages/v_pages [P, page, Hkv*D] (or the
+    [P, page, Hkv, D] view); block_tables [B, pages_per_seq] int32;
+    seq_lens [B] int32.
     """
     B, Hq, D = q.shape
     page = k_pages.shape[1]
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages[0, 0].size // D
     G = Hq // Hkv
     pps = block_tables.shape[1]
     # gather each sequence's pages into a dense [B, S_max, Hkv, D]
@@ -69,8 +70,9 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables,
                                 q_start, q_lens):
     """Fused multi-token-query attention over paged KV (DESIGN.md §11).
 
-    q [B, Q, Hq, D]; k_pages/v_pages [P, page, Hkv, D];
-    block_tables [B, pages_per_seq] int32; q_start/q_lens [B] int32.
+    q [B, Q, Hq, D]; k_pages/v_pages [P, page, Hkv*D] (or the
+    [P, page, Hkv, D] view); block_tables [B, pages_per_seq] int32;
+    q_start/q_lens [B] int32.
     Query token t of row b attends causally over global positions
     <= q_start[b] + t; tokens t >= q_lens[b] are padding (output
     unspecified — callers discard them; here they are zeroed so the
@@ -78,7 +80,7 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables,
     """
     B, Q, Hq, D = q.shape
     page = k_pages.shape[1]
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages[0, 0].size // D
     G = Hq // Hkv
     pps = block_tables.shape[1]
     k = k_pages[block_tables].reshape(B, pps * page, Hkv, D)

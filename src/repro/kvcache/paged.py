@@ -2,7 +2,7 @@
 ``paged_attention`` kernel and the LiveServe KV manager.
 
 The pool owns fixed-size pages of device KV storage
-([num_pages, page_size, Hkv, hd] per layer); sequences own ordered page
+([num_pages, page_size, Hkv*hd] per layer); sequences own ordered page
 lists (prefix-first, matching §5.1's suffix-first eviction). Block tables
 ([B, pages_per_seq] int32) are built per decode batch and handed to the
 kernel via scalar prefetch. A DRAM tier holds offloaded page *contents*
@@ -463,7 +463,7 @@ class PagedPool:
     def offload_suffix(self, seq_id: str, n_pages: int, kv_pages) -> int:
         """Move the LAST n_pages of a sequence to host (suffix-first,
         §5.1), synchronously: begin + complete in one call. kv_pages:
-        device array [num_pages, page, Hkv, hd] (or a pytree leaf).
+        device array [num_pages, page, Hkv*hd] (or a pytree leaf).
         Loading pages in the suffix are cancelled instead of copied
         (their contents only exist on the host). Returns pages freed."""
         cancel_lis, offload_lis = self.evictable_suffix(seq_id, n_pages)

@@ -8,7 +8,7 @@ One scheme, two call sites:
   ``block_scale`` from here so the two tiers can never drift.
 - The paged KV offload path quantizes page payloads on host (numpy)
   before they enter the DRAM tier: ``KVWireCodec`` encodes a page's
-  ``[2, L, page, Hkv, hd]`` host stack to ``(int8 payload, fp32 block
+  ``[2, L, page, Hkv*hd]`` host stack to ``(int8 payload, fp32 block
   scales)`` at offload time and decodes it as the reload chunk lands.
 
 Scheme (per BLOCK-element block):

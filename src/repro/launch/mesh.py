@@ -2,22 +2,43 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state; callers (dryrun, launchers) decide when devices materialize.
+
+Every mesh the repo builds has Auto axes. ``jax.make_mesh`` defaults to
+Explicit axes, under which a ``device_put``/reshard outside a mesh
+context, or a reshape of a sharded batch, is refused; the serving plane
+and the training step rely on both. ``auto_axes`` normalises a mesh a
+caller built itself.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis Auto."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds a 2-pod 'pod' DP axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU lowering tests (run under forced host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(spec: str):
@@ -37,7 +58,7 @@ def make_serving_mesh(spec: str):
             f"mesh {d}x{m} needs {d * m} devices but only {n} present; "
             "set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{d * m} for a virtual host mesh")
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:
@@ -46,9 +67,5 @@ def data_axes(mesh) -> tuple:
 
 
 def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available (jax >= 0.5); on jax 0.4.x
-    the Mesh's own context manager provides the global-mesh semantics."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """``jax.set_mesh`` on the Auto-axis form of ``mesh``."""
+    return jax.set_mesh(auto_axes(mesh))

@@ -17,6 +17,12 @@ Real engine demo (scripted multi-turn conversation, no gateway):
 
   PYTHONPATH=src python -m repro.launch.serve --engine real
 
+``--model qwen2-1.5b`` makes live/real serve that registry config at its
+published widths and dtype (random weights from ``--seed``, created on
+the device) with 16-token pages; without it they serve the reduced
+CPU-runnable config. On a TPU the Pallas kernels compile for the chip;
+elsewhere they run in interpret mode.
+
 walks evict/offload/preload-reload/barge-in through
 PagedRealtimeEngine and reports per-turn TTFT, reload stall, and
 re-prefill tokens. Workload/system flags only apply to --engine
@@ -27,6 +33,8 @@ from __future__ import annotations
 
 import argparse
 import json
+
+from repro.launch.compile_cache import enable_compile_cache
 
 # flags meaningful only for the sim / live engines; --engine real must
 # reject them explicitly instead of silently ignoring them
@@ -43,9 +51,11 @@ def main() -> None:
                          "(DESIGN.md §4); real: scripted paged-engine "
                          "demo (DESIGN.md §3)")
     ap.add_argument("--model", default=None,
-                    choices=["qwen3-omni-like", "ming-omni-like"],
-                    help="sim engine only; live/real serve the reduced "
-                         "CPU-runnable config")
+                    help="sim: a pipeline cost model (qwen3-omni-like | "
+                         "ming-omni-like); live/real: a registry config "
+                         "served at its published widths (e.g. "
+                         "qwen2-1.5b). Default: qwen3-omni-like for sim, "
+                         "the reduced CPU-runnable config for live/real")
     ap.add_argument("--workload", default=None,
                     choices=["sharegpt", "interactive", "mixed",
                              "duplex", "toolcall", "handoff"],
@@ -132,6 +142,7 @@ def main() -> None:
                          "--mesh: every replica shards its page store "
                          "over the same mesh")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.engine != "live":
         live_only = [f"--{f.replace('_', '-')}" for f in
@@ -157,10 +168,6 @@ def main() -> None:
             mesh = make_serving_mesh(args.mesh)
         except ValueError as e:
             ap.error(str(e))
-    if args.engine != "sim" and args.model is not None:
-        ap.error("--model only applies to --engine sim; live/real run "
-                 "the reduced CPU-runnable config")
-
     if args.engine == "real":
         given = [f"--{f.replace('_', '-')}" for f in _WORKLOAD_FLAGS
                  if getattr(args, f) is not None]
@@ -170,9 +177,24 @@ def main() -> None:
                 f"take {', '.join(given)}; use --engine live (real data "
                 f"plane under load) or --engine sim (paper-scale "
                 f"simulation)")
+    from repro.serving.costmodel import PIPELINES
+    model, page_size = None, 8
+    if args.engine == "sim":
+        if args.model is not None and args.model not in PIPELINES:
+            ap.error(f"--engine sim models {'|'.join(PIPELINES)}")
+    elif args.model is not None:
+        from repro.configs import list_configs
+        if args.model not in list_configs():
+            ap.error(f"--model {args.model!r} is not a registry config "
+                     f"({', '.join(list_configs())})")
+        from repro.serving.gateway.harness import published_model
+        model, page_size = published_model(args.model, args.seed), 16
+
+    if args.engine == "real":
         from repro.serving.paged_engine import run_multiturn_demo
         out = run_multiturn_demo(
             seed=args.seed, mesh=mesh, fused_step=args.fused_step,
+            model=model, page_size=page_size,
             log=(lambda *_a, **_k: None) if args.json else print)
         if args.json:
             print(json.dumps(out, indent=1, default=str))
@@ -190,6 +212,7 @@ def main() -> None:
     barge_in = args.barge_in if args.barge_in is not None else 0.0
 
     if args.engine == "live":
+        from repro.serving.gateway.harness import pages_for_context
         bad = [n for n, v in (("--kv-gb", args.kv_gb),
                               ("--concurrency", args.concurrency))
                if v is not None]
@@ -220,7 +243,9 @@ def main() -> None:
             scale=(args.clock_scale
                    if args.clock_scale is not None else 4.0),
             slots=args.slots if args.slots is not None else 8,
-            num_pages=args.kv_pages, mesh=mesh,
+            num_pages=(args.kv_pages if args.kv_pages is not None
+                       or model is None else 4096),
+            mesh=mesh, model=model, page_size=page_size,
             preload_chunks=(args.preload_chunks
                             if args.preload_chunks is not None else 1),
             fused_step=args.fused_step,
@@ -235,12 +260,20 @@ def main() -> None:
                                else 0),
             # the family prefix rides on top of the per-turn prompt
             # draw, so grow each session's context window to fit it
-            # (page_size 8, default pages_per_seq 8)
-            pages_per_seq=8 + -(-(args.family_prefix_len or 0) // 8),
+            # (64 tokens by default)
+            pages_per_seq=pages_for_context(
+                64 + (args.family_prefix_len or 0), page_size),
             frontier_cap_s=3.0 if system == "liveserve" else None)
         if replicas > 1:
             from repro.serving.fleet import run_fleet_workload
-            m, gw = run_fleet_workload(replicas=replicas, **run_kw)
+            import jax
+            # one replica per device where the host has enough of them
+            # (a mesh places its own pages)
+            devices = (jax.devices()[:replicas]
+                       if mesh is None and len(jax.devices()) >= replicas
+                       else None)
+            m, gw = run_fleet_workload(replicas=replicas, devices=devices,
+                                       **run_kw)
             engines = list(gw.replicas)
         else:
             from repro.serving.gateway import run_gateway_workload
@@ -264,7 +297,6 @@ def main() -> None:
                     f"peak_occupancy={m.replica_occupancy[i]:.3f}"
                     + (" [drained]" if i in gw.router.draining else ""))
     else:
-        from repro.serving.costmodel import PIPELINES
         from repro.serving.simulator import run_sim
         from repro.serving.workload import WorkloadConfig
 

@@ -47,7 +47,8 @@ def main() -> None:
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((d, m), ("data", "model"))
 
     params = init_params(cfg, jax.random.PRNGKey(0))
     opt = opt_mod.select_optimizer(cfg)

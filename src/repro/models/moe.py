@@ -141,6 +141,8 @@ def moe_ep(params, x, cfg, mesh, *, data_axes=("data",), model_axis="model"):
     x: [B, S, d] sharded batch->data_axes, d replicated over model_axis.
     Expert weights sharded over model_axis on the expert dim.
     """
+    from repro.launch.mesh import auto_axes
+    mesh = auto_axes(mesh)
     m = cfg.moe
     B, S, d = x.shape
     M = mesh.shape[model_axis]
@@ -199,8 +201,7 @@ def moe_ep(params, x, cfg, mesh, *, data_axes=("data",), model_axis="model"):
         return y[:t].reshape(b_loc, s, d), aux
 
     out_spec = P(None, None, None) if expert_tp else P(DPS, None, None)
-    from repro.compat import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=(out_spec, P()), check_vma=False)(core, x)
     if m.num_shared_experts:

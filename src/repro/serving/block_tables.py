@@ -158,12 +158,12 @@ def assemble_fused(pool: PagedPool,
 
 
 class LayerStackedPages:
-    """Adapts layer-major K/V page arrays ([L, P, page, Hkv, hd], the
+    """Adapts layer-major K/V page arrays ([L, P, page, Hkv*hd], the
     scan-friendly layout the decode step wants) to the PagedPool's
     page-major offload/reload interface (``kv_pages[phys]`` -> host copy;
     ``kv_pages.at[phys].set(copy)`` -> updated store).
 
-    A host copy is the stacked ``[2, L, page, Hkv, hd]`` (k, v) contents
+    A host copy is the stacked ``[2, L, page, Hkv*hd]`` (k, v) contents
     of one physical page — what the DRAM tier stores per page.
     """
 
@@ -202,7 +202,7 @@ class _StoreSet:
         if np.ndim(p) == 0:
             k_new, v_new = hc[0], hc[1]
         else:
-            k_new = np.moveaxis(hc[:, 0], 0, 1)   # [L, n, page, Hkv, hd]
+            k_new = np.moveaxis(hc[:, 0], 0, 1)   # [L, n, page, Hkv*hd]
             v_new = np.moveaxis(hc[:, 1], 0, 1)
         return LayerStackedPages(s.k.at[:, p].set(k_new),
                                  s.v.at[:, p].set(v_new))

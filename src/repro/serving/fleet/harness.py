@@ -1,15 +1,16 @@
 """One-call end-to-end fleet runner (DESIGN.md §12).
 
 Shared by ``launch/serve.py --replicas N``, ``benchmarks/
-gateway_bench.py``'s fleet section, and the fleet tests: build N
-laptop-scale engines on one ``ScaledWallClock`` (one XLA compile — the
-jitted step is shared through the engine's config-keyed cache), put a
+gateway_bench.py``'s fleet section, ``chip_smoke.py --four-chips``
+and the fleet tests: build N engines on one ``ScaledWallClock`` (the
+jitted step is shared through the engine's config-keyed cache; each
+replica may sit on its own device), put a
 ``FleetGateway`` in front, and replay a workload through the same
 in-process clients the single-engine harness uses.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 from repro.core.scheduler import SchedulerConfig
 from repro.distributed.fault_tolerance import StragglerMitigator
@@ -44,18 +45,24 @@ def build_fleet_gateway(*, replicas: int = 3, policy: str = "liveserve",
                         mitigator: Optional[StragglerMitigator] = None,
                         strike_threshold: int = 3,
                         drain_after_routes: Optional[Tuple[int, int]] = None,
-                        rebalance_margin: Optional[int] = None
+                        rebalance_margin: Optional[int] = None,
+                        devices: Optional[Sequence] = None
                         ) -> FleetGateway:
     """N data-parallel engines behind one gateway. All engine knobs are
     per replica (each replica gets its own ``num_pages`` pool); ``mesh``
     composes — every replica shards its page store over the same mesh
-    (DESIGN.md §9 inside §12)."""
+    (DESIGN.md §9 inside §12). ``devices[i]`` pins replica i's params
+    and pages to one device (one replica per chip); None leaves every
+    replica on JAX's default device."""
     from repro.serving.paged_engine import PagedRealtimeEngine
     if autotune:
         from repro.kernels import autotune as at
         at.enable(autotune)
     cfg, params = model if model is not None else tiny_model(seed)
     clock = ScaledWallClock(scale)
+    if devices is None:
+        devices = [None] * replicas
+    assert len(devices) == replicas, (len(devices), replicas)
     engines = [
         PagedRealtimeEngine(cfg, params, slots=slots,
                             page_size=page_size,
@@ -66,11 +73,15 @@ def build_fleet_gateway(*, replicas: int = 3, policy: str = "liveserve",
                             prefix_cache=prefix_cache,
                             kv_quant=kv_quant,
                             spec_decode=spec_decode,
-                            proposer=proposer)
-        for _ in range(replicas)]
-    # one warm-up warms the fleet: replicas share the jitted step
-    # through the config-keyed cache
-    _warm_engine(engines[0], min(prefill_chunk, round_token_budget))
+                            proposer=proposer, device=dev)
+        for dev in devices]
+    # replicas share the jitted step through the config-keyed cache, so
+    # one warm-up per device warms the fleet
+    warmed = set()
+    for e in engines:
+        if e.device not in warmed:
+            warmed.add(e.device)
+            _warm_engine(e, min(prefill_chunk, round_token_budget))
     rs = ReplicaSet(engines, interconnect_gb_s=interconnect_gb_s)
     return FleetGateway(rs, cfg=GatewayConfig(
         policy=policy, audio_per_token_s=audio_per_token_s,

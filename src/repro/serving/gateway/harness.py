@@ -1,8 +1,10 @@
 """One-call end-to-end gateway runner (DESIGN.md §4).
 
-Shared by ``launch/serve.py --engine live``, ``benchmarks/
-gateway_bench.py``, the examples, and the integration tests: build a
-laptop-scale model + ``PagedRealtimeEngine`` on a ``ScaledWallClock``,
+Shared by ``launch/serve.py --engine live``, ``chip_smoke.py``,
+``benchmarks/gateway_bench.py``, the examples, and the integration
+tests: build a model (the laptop-scale ``tiny_model`` by default, or a
+registry config at its published widths via ``published_model``) + a
+``PagedRealtimeEngine`` on a ``ScaledWallClock``,
 put a ``RealtimeGateway`` with the requested policy in front of it, and
 replay a ``serving/workload.py`` trace through in-process clients.
 Returns the same ``Metrics`` object the simulator produces, so
@@ -11,6 +13,7 @@ sim-vs-real comparisons are a dict-diff away.
 from __future__ import annotations
 
 import asyncio
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -32,6 +35,27 @@ def tiny_model(seed: int = 0, vocab: int = 331) -> Tuple[object, dict]:
     cfg = reduced(get_config("qwen2-1.5b"), layers=2, d_model=64,
                   vocab=vocab)
     return cfg, init_params(cfg, jax.random.PRNGKey(seed))
+
+
+def published_model(name: str, seed: int = 0,
+                    device=None) -> Tuple[object, dict]:
+    """A registry config at its published widths and dtype, with random
+    weights drawn from ``seed`` by a jitted ``init_params`` — so they are
+    created on ``device`` (default: JAX's default device), never staged
+    through the host."""
+    from jax.sharding import SingleDeviceSharding
+    from repro.configs import get_config
+    from repro.models import init_params
+    cfg = get_config(name)
+    init = jax.jit(functools.partial(init_params, cfg),
+                   out_shardings=(None if device is None
+                                  else SingleDeviceSharding(device)))
+    return cfg, init(jax.random.PRNGKey(seed))
+
+
+def pages_for_context(tokens: int, page_size: int) -> int:
+    """Block-table width that holds ``tokens`` of committed context."""
+    return -(-tokens // page_size)
 
 
 def _warm_engine(eng, prefill_chunk: int = 1) -> None:

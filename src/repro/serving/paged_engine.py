@@ -5,8 +5,10 @@ Where ``RealtimeLLMEngine`` keeps a dense per-slot ring cache and lives
 for one turn, this engine runs the paper's full KV story on physical
 pages:
 
-- KV lives in a ``PagedPool``-managed page store ([L, P+1, page, Hkv, hd]
-  per K and V; physical page P is a scratch page for padded batch rows).
+- KV lives in a ``PagedPool``-managed page store ([L, P+1, page,
+  Hkv*hd] per K and V — heads merged into the lane dim, the layout the
+  kernel tiles on the chip; physical page P is a scratch page for
+  padded batch rows).
   Decode attends through the Pallas ``paged_attention`` kernel via
   per-round block tables; prefill writes pages through the pool.
 - Sessions are **multi-turn**: when a turn ends (or is barged-in via
@@ -67,13 +69,18 @@ from repro.serving.engine import RoundLimitExceeded, _StepClock, \
 # ======================================================================
 # jitted data plane
 # ======================================================================
+def _merge_heads(x):
+    """[..., Hkv, hd] projections -> [..., Hkv*hd] page rows."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
 def paged_decode_step(cfg, params, tokens, positions, k_pages, v_pages,
                       block_tables, seq_lens, write_page, write_slot,
                       *, interpret: bool = False, plane=None):
     """One token per batch row through the paged KV store.
 
     tokens/positions/write_page/write_slot [B] i32;
-    k_pages/v_pages [L, P+1, page, Hkv, hd]; block_tables [B, pps] i32;
+    k_pages/v_pages [L, P+1, page, Hkv*hd]; block_tables [B, pps] i32;
     seq_lens [B] i32 (post-write attention lengths).
     Returns (logits [B, V], k_pages, v_pages).
 
@@ -90,14 +97,15 @@ def paged_decode_step(cfg, params, tokens, positions, k_pages, v_pages,
         lp, kc, vc = xs
         h = L.rms_norm(carry, lp["ln1"], cfg.rms_eps)
         q, k, v = L.attn_project_qkv(lp["attn"], cfg, h, pos)
+        k, v = _merge_heads(k[:, 0]), _merge_heads(v[:, 0])
         if plane is None:
-            kc = kc.at[write_page, write_slot].set(k[:, 0])
-            vc = vc.at[write_page, write_slot].set(v[:, 0])
+            kc = kc.at[write_page, write_slot].set(k)
+            vc = vc.at[write_page, write_slot].set(v)
             a = paged_attention(q[:, 0], kc, vc, block_tables, seq_lens,
                                 interpret=interpret)
         else:
-            kc, vc = plane.write_token(kc, vc, k[:, 0], v[:, 0],
-                                       write_page, write_slot)
+            kc, vc = plane.write_token(kc, vc, k, v, write_page,
+                                       write_slot)
             a = plane.attend(q[:, 0], kc, vc, block_tables, seq_lens,
                              interpret=interpret)
         h = carry + L.attn_output(lp["attn"], a[:, None])
@@ -125,8 +133,8 @@ def paged_fused_step(cfg, params, tokens, positions, k_pages, v_pages,
 
     tokens/positions/write_pages/write_slots [B, Q] i32;
     q_start/q_lens [B] i32 (first absolute position / valid tokens per
-    row — 0 marks a padding row); k_pages/v_pages [L, P+1, page, Hkv,
-    hd]; block_tables [B, pps] i32. Returns (logits [B, V] of each
+    row — 0 marks a padding row); k_pages/v_pages [L, P+1, page,
+    Hkv*hd]; block_tables [B, pps] i32. Returns (logits [B, V] of each
     row's *last valid* token, k_pages, v_pages); with ``spec`` (the
     speculative verify variant, DESIGN.md §16) the result is (logits,
     outs [B, Q] i32, k_pages, v_pages) where ``outs[b, t]`` is the
@@ -149,6 +157,7 @@ def paged_fused_step(cfg, params, tokens, positions, k_pages, v_pages,
         lp, kc, vc = xs
         h = L.rms_norm(carry, lp["ln1"], cfg.rms_eps)
         q, k, v = L.attn_project_qkv(lp["attn"], cfg, h, positions)
+        k, v = _merge_heads(k), _merge_heads(v)
         if plane is None:
             kc = kc.at[write_pages, write_slots].set(k)
             vc = vc.at[write_pages, write_slots].set(v)
@@ -286,7 +295,7 @@ class PagedRealtimeEngine:
                  prefix_cache: bool = False,
                  kv_quant: str = "fp32",
                  spec_decode: int = 0,
-                 proposer=None):
+                 proposer=None, device=None):
         assert cfg.family in ("dense", "moe", "vlm") and cfg.mla is None \
             and cfg.sliding_window is None, \
             "paged engine serves global-attention KV families"
@@ -312,20 +321,27 @@ class PagedRealtimeEngine:
         # (or page slots) over the mesh's 'model' axis; weights, block
         # tables, and the decode batch stay replicated, so every host-
         # side policy/pool path below is mesh-agnostic.
+        # ``device`` pins a replica's params and pages to one device
+        # (the fleet puts replica i on jax.devices()[i]); None keeps
+        # JAX's default device.
         self.mesh = mesh
         self.layout = None
+        self.device = device
+        assert mesh is None or device is None, "a mesh places its own"
         if mesh is not None:
             from repro.distributed.paged import PagedKVLayout
             self.layout = PagedKVLayout(cfg, mesh, page_size)
             params = jax.device_put(params, self.layout.replicated)
+        elif device is not None:
+            params = jax.device_put(params, device)
         self.params = params
 
         hd = cfg.resolved_head_dim
         dtype = jnp.dtype(cfg.dtype)
         shape = (cfg.num_layers, self.num_pages + 1, page_size,
-                 cfg.num_kv_heads, hd)
-        self.k_pages = jnp.zeros(shape, dtype)
-        self.v_pages = jnp.zeros(shape, dtype)
+                 cfg.num_kv_heads * hd)
+        self.k_pages = jnp.zeros(shape, dtype, device=device)
+        self.v_pages = jnp.zeros(shape, dtype, device=device)
         self._place_pages()
         bytes_per_token = 2 * cfg.num_layers * cfg.num_kv_heads * hd \
             * dtype.itemsize
@@ -393,7 +409,10 @@ class PagedRealtimeEngine:
         self.slot_state: Dict[int, Optional[PagedSlot]] = {
             i: None for i in range(slots)}
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            # Pallas compiles for the chip the pages live on and runs
+            # interpreted anywhere else (the CPU test suite)
+            interpret = next(iter(self.k_pages.devices())).platform != "tpu"
+        self.interpret = interpret
         self._step_fn = _jitted_step(cfg, interpret, self.layout)
         # the fused token-budget plane (DESIGN.md §11): one launch per
         # round, C-token prefill chunks included. fused_step=False keeps
@@ -552,7 +571,7 @@ class PagedRealtimeEngine:
         if self.layout is not None:
             staged = self.layout.stage_host_chunk(host)
         else:
-            staged = jnp.asarray(host)
+            staged = jax.device_put(host, self.device)
         jax.block_until_ready(staged)
         self.reload_wall_s.append(time.perf_counter() - t0)
         store = self.pool.complete_reload(
@@ -567,7 +586,7 @@ class PagedRealtimeEngine:
         to host copies, then free the slots (copy-then-free step 2)."""
         s = self.pool.seq(sid)
         phys = np.asarray([s.pages[li] for li in lis], np.int64)
-        hk = np.asarray(self.k_pages[:, phys])     # [L, n, page, Hkv, hd]
+        hk = np.asarray(self.k_pages[:, phys])     # [L, n, page, Hkv*hd]
         hv = np.asarray(self.v_pages[:, phys])
         self.pool.complete_offload(
             sid, {li: self.codec.encode(np.stack([hk[:, i], hv[:, i]]))
@@ -962,7 +981,7 @@ class PagedRealtimeEngine:
                              jnp.asarray(prompt, jnp.int32)[None, :], c1)
         phys = np.asarray(self.pool.seq(sid).pages[:npages], np.int64)
         kl = c1["k"][:, 0].reshape(self.cfg.num_layers, npages,
-                                   self.page_size, *c1["k"].shape[3:])
+                                   self.page_size, -1)
         vl = c1["v"][:, 0].reshape(kl.shape)
         self.k_pages = self.k_pages.at[:, phys].set(kl)
         self.v_pages = self.v_pages.at[:, phys].set(vl)
@@ -1698,9 +1717,10 @@ class PagedRealtimeEngine:
 # demo driver (launch/serve.py --engine real and examples/)
 # ======================================================================
 def run_multiturn_demo(*, seed: int = 0, mesh=None,
-                       fused_step: bool = True, log=print) -> dict:
-    """A laptop-scale end-to-end conversation on the real data plane,
-    walking the whole §5 mechanism:
+                       fused_step: bool = True, model=None,
+                       page_size: int = 8, log=print) -> dict:
+    """An end-to-end conversation on the real data plane, walking the
+    whole §5 mechanism:
 
     1. alice's turn 1 prefills+decodes; her reply keeps playing.
     2. bob's heavy session *physically* evicts alice's suffix pages to
@@ -1714,36 +1734,48 @@ def run_multiturn_demo(*, seed: int = 0, mesh=None,
        the utterance: his turn 2 starts warm (zero stall, zero
        re-prefill).
 
+    ``model`` is a ``(cfg, params)`` pair (default: the laptop-scale
+    reduced config). The pool is always 11 pages, so the evictions above
+    happen at any width; every token count scales with ``page_size / 8``
+    so each turn spans the same number of pages.
+
     Returns per-turn stats for both sessions.
     """
     from repro.configs import get_config, reduced
     from repro.models import init_params
 
-    cfg = reduced(get_config("qwen2-1.5b"), layers=2, d_model=64,
-                  vocab=503)
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    # pcie_gb_s scaled down with the laptop-scale pool (KB, not GB) so
-    # transfer times land in the milliseconds the paper plots
-    eng = PagedRealtimeEngine(cfg, params, slots=2, page_size=8,
-                              pages_per_seq=9, num_pages=11,
-                              pcie_gb_s=0.01, mesh=mesh,
-                              fused_step=fused_step)
+    assert page_size % 8 == 0, "token counts scale with page_size / 8"
+    x = page_size // 8
+    if model is None:
+        cfg = reduced(get_config("qwen2-1.5b"), layers=2, d_model=64,
+                      vocab=503)
+        params = init_params(cfg, jax.random.PRNGKey(seed))
+        # pcie_gb_s scaled down with the laptop-scale pool (KB, not GB)
+        # so transfer times land in the milliseconds the paper plots
+        pcie = dict(pcie_gb_s=0.01)
+    else:
+        (cfg, params), pcie = model, {}
+    eng = PagedRealtimeEngine(cfg, params, slots=2, page_size=page_size,
+                              pages_per_seq=9, num_pages=11, mesh=mesh,
+                              fused_step=fused_step, **pcie)
     rng = np.random.default_rng(seed)
 
     def prompt(n):
         return rng.integers(0, cfg.vocab_size, size=n)
 
-    log(f"engine: {cfg.name} slots=2 page=8 pool={eng.num_pages} pages"
+    log(f"engine: {cfg.name} L={cfg.num_layers} d={cfg.d_model} "
+        f"vocab={cfg.vocab_size} {cfg.dtype} slots=2 page={page_size} "
+        f"pool={eng.num_pages} pages interpret={eng.interpret}"
         + (f" layout={eng.layout}" if eng.layout else ""))
     # ---- alice turn 1: admitted, decoded to completion -------------
-    eng.add_session("alice", prompt(28), max_new_tokens=10)
+    eng.add_session("alice", prompt(28 * x), max_new_tokens=10 * x)
     eng.run_to_completion()
     eng.monitor.on_audio("alice", 30.0)     # long reply still playing
     log(f"alice turn 1: kv_len={eng.sessions['alice'].kv_len} "
         f"pages={eng.pool.resident_pages('alice')}")
 
     # ---- pool pressure: bob's growth evicts alice's suffix ---------
-    eng.add_session("bob", prompt(30), max_new_tokens=26)
+    eng.add_session("bob", prompt(30 * x), max_new_tokens=26 * x)
     eng.run_to_completion()
     eng.monitor.on_audio("bob", 60.0)
     res, off = eng.monitor.page_counts("alice")
@@ -1758,14 +1790,14 @@ def run_multiturn_demo(*, seed: int = 0, mesh=None,
         f"sync fallback on turn start)")
 
     # ---- alice turn 2: on-path reload, zero re-prefill; barge-in ---
-    eng.start_turn("alice", prompt(6), max_new_tokens=12)
-    for _ in range(4):
+    eng.start_turn("alice", prompt(6 * x), max_new_tokens=12 * x)
+    for _ in range(4 * x):
         eng.step()
     eng.barge_in("alice", expected_dur_s=1.0)
     eng.clock.tick(1.0)
 
     # ---- alice turn 3 resumes on committed pages -------------------
-    eng.start_turn("alice", prompt(5), max_new_tokens=6)
+    eng.start_turn("alice", prompt(5 * x), max_new_tokens=6 * x)
     eng.run_to_completion()
     eng.check_invariants()
 
@@ -1780,7 +1812,7 @@ def run_multiturn_demo(*, seed: int = 0, mesh=None,
         f"hits pending; resident={res} offloaded={off}")
 
     # ---- bob turn 2: warm KV, zero stall, zero re-prefill ----------
-    eng.start_turn("bob", prompt(6), max_new_tokens=6)
+    eng.start_turn("bob", prompt(6 * x), max_new_tokens=6 * x)
     eng.run_to_completion()
     eng.check_invariants()
 
@@ -1801,6 +1833,7 @@ def run_multiturn_demo(*, seed: int = 0, mesh=None,
     log(f"preload: {eng.preloader.stats}")
     log(f"pool: {eng.pool.stats()}  evictions={len(eng.offload_events)}")
     return {"turns": all_stats,
+            "interpret": eng.interpret,
             "preload": vars(eng.preloader.stats),
             "pool": eng.pool.stats(),
             "offload_events": len(eng.offload_events)}

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import auto_axes
 from repro.models import model as M
 from repro.training import optimizer as opt_mod
 
@@ -50,7 +51,8 @@ def build_train_step(cfg, opt: opt_mod.OptConfig, tc: TrainConfig,
                      mesh=None):
     """Returns train_step(params, opt_state, batch) -> (params, opt_state,
     metrics)."""
-
+    if mesh is not None:
+        mesh = auto_axes(mesh)
     seq_spec = None
     if tc.seq_shard_activations and mesh is not None:
         da = tuple(a for a in mesh.axis_names if a != "model")

@@ -175,7 +175,7 @@ def _step_case(cfg, B, page, pps, key):
     num_pages = B * pps
     ks = jax.random.split(key, 4)
     kp = jax.random.normal(
-        ks[0], (cfg.num_layers, num_pages + 1, page, cfg.num_kv_heads, hd))
+        ks[0], (cfg.num_layers, num_pages + 1, page, cfg.num_kv_heads * hd))
     vp = jax.random.normal(ks[1], kp.shape)
     perm = np.asarray(jax.random.permutation(ks[2], num_pages))
     bt = perm[:B * pps].reshape(B, pps).astype(np.int32)
