@@ -24,6 +24,8 @@ import itertools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from repro.serving.tracing import span
+
 
 @dataclass
 class SessionKV:
@@ -161,7 +163,6 @@ class KVManager:
         # telemetry
         self.evicted_blocks = 0
         self.reloaded_blocks = 0
-        self.eviction_overhead_s: List[float] = []
         self.residency_log: List[Tuple[float, int]] = []
 
     # ------------------------------------------------------------- hooks
@@ -328,30 +329,30 @@ class KVManager:
         Suffix blocks of the selected session go first; the session's HBM
         range shrinks from the tail (prefix continuity preserved).
         """
-        import time as _time
-        t0 = _time.perf_counter()
-        freed = 0
-        if self.policy == "next_use" and self.index_mode == "heap":
-            # seed the heap lazily: unseen evictable sessions, plus
-            # sessions evictable again without an interaction event
-            # (protection TTL lapsed, a candidate pop rejected them
-            # earlier) whose current version is no longer live in it
-            for sid, kv in self.sessions.items():
-                if kv.evictable(now) > 0 \
-                        and not self._in_heap.get(sid, False):
-                    self._push_index(sid, now)
-            while freed < need_blocks:
-                sid = self._pop_heap_candidate(now)
-                if sid is None:
-                    break
-                freed += self._evict_session(sid, need_blocks - freed, now)
-        else:
-            for sid in self._candidates_scan(now):
-                if freed >= need_blocks:
-                    break
-                freed += self._evict_session(sid, need_blocks - freed, now)
-        self.eviction_overhead_s.append(_time.perf_counter() - t0)
-        return freed
+        with span("serve.kv.evict", pages=need_blocks):
+            freed = 0
+            if self.policy == "next_use" and self.index_mode == "heap":
+                # seed the heap lazily: unseen evictable sessions, plus
+                # sessions evictable again without an interaction event
+                # (protection TTL lapsed, a candidate pop rejected them
+                # earlier) whose current version is no longer live in it
+                for sid, kv in self.sessions.items():
+                    if kv.evictable(now) > 0 \
+                            and not self._in_heap.get(sid, False):
+                        self._push_index(sid, now)
+                while freed < need_blocks:
+                    sid = self._pop_heap_candidate(now)
+                    if sid is None:
+                        break
+                    freed += self._evict_session(sid, need_blocks - freed,
+                                                 now)
+            else:
+                for sid in self._candidates_scan(now):
+                    if freed >= need_blocks:
+                        break
+                    freed += self._evict_session(sid, need_blocks - freed,
+                                                 now)
+            return freed
 
     def _evict_session(self, sid: str, want: int, now: float) -> int:
         kv = self.sessions[sid]

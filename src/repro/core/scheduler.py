@@ -85,6 +85,10 @@ class ScheduleDecision:
     classes: dict                    # req_id -> 0/1/2/3 (telemetry/debug)
     utilities: dict = field(default_factory=dict)
     held: list = field(default_factory=list)   # (req, buffer) paced out
+    # req_id -> why a candidate got no grant: "slot" (no batch row to
+    # bind), "budget" (behind Algorithm 1's token/KV budget break); the
+    # gateway's binding adds "slot" and "pages" (OutOfPages requeue)
+    waiting: dict = field(default_factory=dict)
 
 
 class UrgencyScheduler:
@@ -188,8 +192,8 @@ class UrgencyScheduler:
             c2.sort(key=lambda t: t[:2])
             order = [t[2] for t in c0 + c1 + c2]
 
-        batch, chunks = [], {}
-        for r in order:
+        batch, chunks, waiting = [], {}, {}
+        for k, r in enumerate(order):
             needs_slot = budget.free_slots is not None \
                 and not r.slot_bound
             if needs_slot and budget.free_slots <= 0:
@@ -197,10 +201,13 @@ class UrgencyScheduler:
                 # slots are a different resource from the token budget,
                 # and stopping here would starve the live decode slots
                 # this very turn is waiting on (head-of-line livelock)
+                waiting[r.req_id] = "slot"
                 continue
             chunk = self.chunk_for(r)
             if not budget.fits(r, chunk):
-                break                 # Algorithm 1: admission stops
+                # Algorithm 1: admission stops
+                waiting.update((x.req_id, "budget") for x in order[k:])
+                break
             budget.admit(r, chunk)
             if needs_slot:
                 budget.free_slots -= 1
@@ -208,7 +215,8 @@ class UrgencyScheduler:
             chunks[r.req_id] = chunk
             r.last_scheduled = now
         return ScheduleDecision(batch=batch, chunks=chunks, classes=classes,
-                                utilities=utilities, held=held)
+                                utilities=utilities, held=held,
+                                waiting=waiting)
 
     def hold_wake_s(self, decision: ScheduleDecision,
                     now: Optional[float] = None) -> Optional[float]:

@@ -46,6 +46,9 @@ class Request:
     last_scheduled: float = -1.0
     reload_stall_s: float = 0.0         # on-path KV reload charged to TTFP
     reload_off_path_s: float = 0.0      # reload seconds hidden off-path
+    reload_on_path_wall_s: float = 0.0  # measured host seconds of the
+    #                                     turn-start settlement, when it
+    #                                     landed pages on the turn's path
     prefix_hit_tokens: int = 0          # prompt tokens served from the
     #                                     shared prefix cache (skip-ahead)
     slot_bound: bool = True             # already holds a batch row; False

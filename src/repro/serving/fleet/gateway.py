@@ -31,6 +31,7 @@ from repro.serving.fleet.migration import (MigrationCoordinator,
 from repro.serving.fleet.replica_set import ReplicaSet
 from repro.serving.fleet.router import SessionRouter
 from repro.serving.metrics import Metrics
+from repro.serving.tracing import span
 
 
 class FleetGateway(RealtimeGateway):
@@ -123,23 +124,27 @@ class FleetGateway(RealtimeGateway):
                     if self.router.placement.get(sid) == i}
             before = set(pend)
             t0 = time.perf_counter()
-            decision, chunks, admitted = control_round(
-                eng, self.schedulers[i], pend,
-                token_budget=self.cfg.round_token_budget,
-                frontier_cap_s=self.cfg.frontier_cap_s,
-                record_admit=self._record_admit)
+            with span("serve.schedule"):
+                decision, chunks, admitted = control_round(
+                    eng, self.schedulers[i], pend,
+                    token_budget=self.cfg.round_token_budget,
+                    frontier_cap_s=self.cfg.frontier_cap_s,
+                    record_admit=self._record_admit)
             # control_round pops what it admitted (and re-inserts an
             # OutOfPages requeue); sync the filtered view back
             for sid in before - set(pend):
                 self._pending.pop(sid, None)
             if decision is None:
                 continue
+            if before:
+                self._count_admission(decision, pend)
             self.last_decision = decision
             if chunks:
                 sids = {j: eng.slot_state[j].session_id for j in chunks}
                 events = eng.run_round(chunks)
                 self.rounds += 1
-                self._dispatch(events, sids)
+                with span("serve.dispatch"):
+                    self._dispatch(events, sids)
                 self.router.observe_round(
                     i, time.perf_counter() - t0
                     + self.round_lag_s.get(i, 0.0))

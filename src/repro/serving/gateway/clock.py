@@ -8,12 +8,13 @@ multiplied by ``scale`` so a 2.5 s utterance takes 2.5/scale real
 seconds while every policy still sees paper-scale durations (playback
 drains at 1 clock-second per clock-second by construction).
 
-``tick(dt)`` keeps the engines' modelled-cost contract: synchronous
-paths charge modelled time (e.g. the on-path KV reload residual from the
-TransferChannel) by advancing a constant offset — time the data plane
-did not physically spend but the policy plane must account for. Real
-compute (prefill/decode steps) advances the clock by actually taking
-wall time, so the engine's default per-round ``tick()`` is a no-op here.
+``tick(dt)`` keeps the engines' modelled-cost contract: a path may
+charge modelled time (e.g. a fleet migration's on-path residual) by
+advancing a constant offset — time the data plane did not physically
+spend but the policy plane must account for. Real compute
+(prefill/decode steps, a turn-start KV reload) advances the clock by
+actually taking wall time, so the engine neither charges its reload
+residual here (``self_advancing``) nor needs its per-round ``tick()``.
 """
 from __future__ import annotations
 
@@ -22,6 +23,9 @@ import time
 
 
 class ScaledWallClock:
+    # time passes by itself: work already spent shows up in now()
+    self_advancing = True
+
     def __init__(self, scale: float = 1.0):
         assert scale > 0.0
         self.scale = scale

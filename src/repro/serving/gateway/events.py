@@ -31,7 +31,9 @@ Gateway -> client:
 
 Events carry the *session-local* wall-clock timestamp ``t`` stamped by
 whoever created them; the gateway re-stamps arrival against its own
-scaled clock, so clients cannot skew serving-side metrics.
+scaled clock, so clients cannot skew serving-side metrics. ``t_wall``
+is the host's monotonic clock (``time.perf_counter``) at the moment the
+event entered the gateway's inbox, which no modelled cost advances.
 """
 from __future__ import annotations
 
@@ -45,6 +47,7 @@ import numpy as np
 class SessionEvent:
     session_id: str
     t: float = 0.0                  # sender-side scaled-clock timestamp
+    t_wall: float = 0.0             # perf_counter when enqueued
 
 
 # --------------------------------------------------- client -> gateway

@@ -30,6 +30,7 @@ admissions are atomic with respect to each other — that is the
 from __future__ import annotations
 
 import asyncio
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -47,6 +48,7 @@ from repro.serving.gateway.events import (AudioChunk, BargeIn, Hangup,
                                           ToolCallStart, TurnDone,
                                           TurnRequest, UserAudio)
 from repro.serving.metrics import Metrics, TurnRecord
+from repro.serving.tracing import span
 
 
 @dataclass
@@ -86,6 +88,8 @@ class GatewaySession:
     outbox: asyncio.Queue
     turn_no: int = -1                    # last TurnRequest's index
     closed: bool = False
+    speech_end_wall: Optional[float] = None   # SpeechEnd not yet taken
+    #                                            by a TurnRequest
 
 
 class SessionHandle:
@@ -98,6 +102,7 @@ class SessionHandle:
 
     async def send(self, ev: SessionEvent) -> None:
         ev.t = self._gw.clock.now()
+        ev.t_wall = time.perf_counter()
         await self._gw._inbox.put(ev)
 
     async def recv(self) -> SessionEvent:
@@ -145,10 +150,20 @@ def record_admitted_turn(rec, r: Request) -> None:
     sim/real differential cannot drift field-by-field."""
     rec.reload_stall_s = r.reload_stall_s
     rec.reload_off_path_s = r.reload_off_path_s
+    rec.reload_on_path_wall_s = r.reload_on_path_wall_s
     rec.prefix_hit_tokens = r.prefix_hit_tokens
     # prompt_len counts only the tokens left to prefill after a prefix
     # attach; the record keeps the client-visible total
     rec.prompt_tokens = r.prompt_len + r.prefix_hit_tokens
+
+
+def over_frontier(eng, sid: str, frontier_cap_s: Optional[float]) -> bool:
+    """The session's client buffer is past the hard playback-frontier
+    cap (§4)."""
+    if frontier_cap_s is None:
+        return False
+    buf = eng.monitor.playback_buffer_s(sid)
+    return buf is not None and buf > frontier_cap_s
 
 
 def control_round(eng, scheduler, pending, *, token_budget: int,
@@ -167,20 +182,13 @@ def control_round(eng, scheduler, pending, *, token_budget: int,
     now = eng.clock.now()
     ready: List[Request] = []
     owner: Dict[int, tuple] = {}
-
-    def over_frontier(sid: str) -> bool:
-        if frontier_cap_s is None:
-            return False
-        buf = eng.monitor.playback_buffer_s(sid)
-        return buf is not None and buf > frontier_cap_s
-
     for i, s in eng.slot_state.items():
         if s is None or not s.request.is_live():
             continue
         if s.request.generated >= s.request.max_new_tokens:
             continue
         if s.request.phase == Phase.DECODE \
-                and over_frontier(s.session_id):
+                and over_frontier(eng, s.session_id, frontier_cap_s):
             continue                         # hard frontier cap (§4)
         s.request.slot_bound = True
         ready.append(s.request)
@@ -206,6 +214,7 @@ def control_round(eng, scheduler, pending, *, token_budget: int,
             chunks[key] = decision.chunks[r.req_id]
             continue
         if eng.free_slot() is None:
+            decision.waiting[r.req_id] = "slot"
             continue                         # all slots busy; stay queued
         p = pending.pop(key)
         try:
@@ -217,6 +226,7 @@ def control_round(eng, scheduler, pending, *, token_budget: int,
             # turn queued — pressure drains as turns finish or barge-ins
             # trim
             pending[key] = p
+            decision.waiting[r.req_id] = "pages"
             continue
         record_admit(key, r)
         admitted = True                      # prefill starts next round
@@ -259,6 +269,16 @@ class RealtimeGateway:
         self._stopping = False
         self._force_stop = False
         self.rounds = 0
+        # admission counters: control rounds with a turn queued at
+        # schedule time; of those, rounds that left a turn queued behind
+        # Algorithm 1's budget break, or only for want of a free slot;
+        # turns requeued on OutOfPages
+        self.queued_rounds = 0
+        self.budget_held_rounds = 0
+        self.slot_held_rounds = 0
+        self.requeued_turns = 0
+        # waits of the serve loop after a round that ran nothing
+        self.idle_waits = {"empty": 0, "frontier": 0, "held": 0}
         # frontier telemetry: worst observed client buffer beyond the
         # configured cap at token-emission time (the §4 invariant)
         self.max_over_frontier_s = 0.0
@@ -315,6 +335,7 @@ class RealtimeGateway:
         sid = ev.session_id
         eng = self._eng(sid)
         if isinstance(ev, SpeechStart):
+            self._sessions[sid].speech_end_wall = None
             # fires the §5.2 speech-time preload while the user talks
             eng.user_speech_start(sid, expected_dur_s=ev.expected_dur_s)
         elif isinstance(ev, UserAudio):
@@ -322,6 +343,7 @@ class RealtimeGateway:
             #         events (SpeechStart/End) carry the policy signal
         elif isinstance(ev, SpeechEnd):
             eng.monitor.on_speech_end(sid)
+            self._sessions[sid].speech_end_wall = ev.t_wall
         elif isinstance(ev, TurnRequest):
             self._on_turn_request(ev)
         elif isinstance(ev, BargeIn):
@@ -353,6 +375,9 @@ class RealtimeGateway:
                                          ev.max_new_tokens, req)
         rec = self._rec(sid)
         rec.speech_end = now
+        rec.wall_speech_end, gs.speech_end_wall = gs.speech_end_wall, None
+        rec.wall_request = ev.t_wall
+        rec.wall_handled = time.perf_counter()
         if ev.frame_period_s > 0.0:
             self._eng(sid).monitor.on_frame_turn(sid, ev.frame_period_s)
         rec.tool_resumed = ev.tool_resume
@@ -419,25 +444,45 @@ class RealtimeGateway:
 
     # ------------------------------------------------------------ rounds
     def _record_admit(self, sid: str, r: Request) -> None:
-        record_admitted_turn(self._rec(sid), r)
+        rec = self._rec(sid)
+        record_admitted_turn(rec, r)
+        rec.wall_admitted = time.perf_counter()
+
+    def _count_admission(self, decision, pending) -> None:
+        """Why the turns queued at this round's schedule are still
+        queued after it (``pending`` as the round left it)."""
+        self.queued_rounds += 1
+        why = {decision.waiting.get(p.request.req_id)
+               for p in pending.values()}
+        if "budget" in why:
+            self.budget_held_rounds += 1
+        elif why == {"slot"}:
+            self.slot_held_rounds += 1
+        self.requeued_turns += sum(
+            1 for w in decision.waiting.values() if w == "pages")
 
     def _round(self) -> bool:
         """One scheduler-driven round. Returns True if any work ran."""
         eng = self.engine
-        decision, chunks, admitted = control_round(
-            eng, self.scheduler, self._pending,
-            token_budget=self.cfg.round_token_budget,
-            frontier_cap_s=self.cfg.frontier_cap_s,
-            record_admit=self._record_admit)
+        queued = bool(self._pending)
+        with span("serve.schedule"):
+            decision, chunks, admitted = control_round(
+                eng, self.scheduler, self._pending,
+                token_budget=self.cfg.round_token_budget,
+                frontier_cap_s=self.cfg.frontier_cap_s,
+                record_admit=self._record_admit)
         if decision is None:
             return False
+        if queued:
+            self._count_admission(decision, self._pending)
         self.last_decision = decision
         if not chunks:
             return admitted
         sids = {i: eng.slot_state[i].session_id for i in chunks}
         events = eng.run_round(chunks)
         self.rounds += 1
-        self._dispatch(events, sids)
+        with span("serve.dispatch"):
+            self._dispatch(events, sids)
         return True
 
     def _dispatch(self, events: Dict[int, List[tuple]],
@@ -466,6 +511,8 @@ class RealtimeGateway:
                     gs.outbox.put_nowait(AudioChunk(
                         sid, t=now, turn_index=gs.turn_no, dur_s=apt,
                         token=val))
+                    if rec.wall_first_packet is None:
+                        rec.wall_first_packet = time.perf_counter()
                 elif kind == "finished":
                     v = eng.monitor.view(sid)
                     rec.max_gap_s = (v.playback.max_gap_s
@@ -486,7 +533,8 @@ class RealtimeGateway:
                 ev = self._inbox.get_nowait()
             except asyncio.QueueEmpty:
                 break
-            self._handle(ev)
+            with span("serve.handle", kind=type(ev).__name__):
+                self._handle(ev)
             n += 1
         return n
 
@@ -507,6 +555,23 @@ class RealtimeGateway:
             return
         for eng in self._engines():
             eng.drain_transfers(self.cfg.idle_transfer_chunks)
+
+    def _idle_reason(self) -> str:
+        """Why a round ran nothing: no live work (``empty``), every
+        live slot decoding past the frontier cap (``frontier``), or work
+        the scheduler held back (``held``)."""
+        cap = self.cfg.frontier_cap_s
+        live = [(eng, s) for eng in self._engines()
+                for s in eng.slot_state.values()
+                if s is not None and s.request.is_live()]
+        if not live and not self._pending:
+            return "empty"
+        if not self._pending and all(
+                s.request.phase == Phase.DECODE
+                and over_frontier(eng, s.session_id, cap)
+                for eng, s in live):
+            return "frontier"
+        return "held"
 
     def _hold_wake(self) -> Optional[float]:
         ld = getattr(self, "last_decision", None)
@@ -536,9 +601,13 @@ class RealtimeGateway:
             held = self._hold_wake()
             if held is not None:
                 wake = min(wake, held)
+            reason = self._idle_reason()
+            self.idle_waits[reason] += 1
             try:
-                ev = await asyncio.wait_for(
-                    self._inbox.get(), timeout=self.clock.real_s(wake))
-                self._handle(ev)
+                with span("serve.idle", reason=reason):
+                    ev = await asyncio.wait_for(
+                        self._inbox.get(), timeout=self.clock.real_s(wake))
+                with span("serve.handle", kind=type(ev).__name__):
+                    self._handle(ev)
             except asyncio.TimeoutError:
                 pass

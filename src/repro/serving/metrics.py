@@ -46,10 +46,28 @@ class TurnRecord:
     tool_resumed: bool = False             # turn resumed a tool pause
     handoff: bool = False                  # turn started on a replica the
     #                                        client requested via handoff
+    # the live gateway's host monotonic stamps (time.perf_counter) along
+    # the turn's path; None on the virtual-clock twins
+    wall_speech_end: Optional[float] = None    # SpeechEnd enqueued
+    wall_request: Optional[float] = None       # TurnRequest enqueued
+    wall_handled: Optional[float] = None       # TurnRequest handled
+    wall_admitted: Optional[float] = None      # turn bound to a slot
+    wall_first_packet: Optional[float] = None  # first AudioChunk queued
+    reload_on_path_wall_s: float = 0.0     # measured turn-start reload
 
     @property
     def continuous(self) -> bool:
         return self.max_gap_s <= 0.100
+
+    @property
+    def ttfp_wall_s(self) -> Optional[float]:
+        """TTFP on the host's monotonic clock: SpeechEnd (or, for a
+        turn with none before it, the TurnRequest) to the first packet."""
+        start = self.wall_speech_end if self.wall_speech_end is not None \
+            else self.wall_request
+        if start is None or self.wall_first_packet is None:
+            return None
+        return self.wall_first_packet - start
 
     @property
     def rtf(self) -> Optional[float]:
@@ -191,6 +209,8 @@ class Metrics:
 
     def summary(self) -> dict:
         tt = self.ttfps()
+        wall = sorted(t.ttfp_wall_s for t in self.turns
+                      if t.ttfp_wall_s is not None)
         rtfs = sorted(t.rtf for t in self.turns if t.rtf is not None)
         stalls = [t.reload_stall_s for t in self.turns]
         offs = [t.reload_off_path_s for t in self.turns]
@@ -199,6 +219,10 @@ class Metrics:
             "p50_ttfp": self.percentile(tt, 50),
             "p90_ttfp": self.percentile(tt, 90),
             "p95_ttfp": self.percentile(tt, 95),
+            # the live gateway's TTFP on an unmodelled clock; None on
+            # the virtual-clock twins
+            "p50_ttfp_wall": self.percentile(wall, 50) if wall else None,
+            "p90_ttfp_wall": self.percentile(wall, 90) if wall else None,
             "continuity": self.continuity(),
             "waste_ratio": self.waste_ratio(),
             "completed_rps": self.completed_rps(),

@@ -64,6 +64,7 @@ from repro.serving.block_tables import BatchTables, FusedBatchTables, \
     LayerStackedPages, assemble, assemble_fused
 from repro.serving.engine import RoundLimitExceeded, _StepClock, \
     schedule_round
+from repro.serving.tracing import span
 
 
 # ======================================================================
@@ -273,6 +274,7 @@ class PagedSession:
     turn_arrival: float = 0.0
     reload_stall_s: float = 0.0     # on-path stall charged to this turn
     reload_off_path_s: float = 0.0  # reload seconds hidden off-path
+    reload_on_path_wall_s: float = 0.0  # measured on-path settlement
     ended: bool = False             # user hung up; pages released
     history: List[List[int]] = field(default_factory=list)
     turn_stats: List[dict] = field(default_factory=list)
@@ -444,6 +446,15 @@ class PagedRealtimeEngine:
         self.offload_events: List[tuple] = []
         self.pressure_holds = 0                # feeds held mid-round
         self.fused_launches = 0                # fused-plane step launches
+        # token slots of every launch: valid tokens fed, and the grid
+        # (slots x Q bucket) the step computed
+        self.launch_valid_tokens = 0
+        self.launch_grid_tokens = 0
+        # reload pages landed inside a turn-start settlement (on the
+        # turn's path) and by transfer drains (off it)
+        self.settled_reload_pages = 0
+        self.drained_reload_pages = 0
+        self._settling = False
         self.peak_shared_pages = 0             # max pages with refcount>1
         self.cow_copies = 0                    # copy-on-write page copies
         # speculation accounting (the §16 invariant:
@@ -565,40 +576,48 @@ class PagedRealtimeEngine:
         to the device and *only that buffer* is blocked on for the
         wall-time measurement — blocking on the whole page store would
         over-synchronize unrelated decode work (ISSUE 4 satellite)."""
-        s = self.pool.seq(sid)
-        host = np.stack([self.codec.decode(s.offloaded[li]) for li in lis])
-        t0 = time.perf_counter()
-        if self.layout is not None:
-            staged = self.layout.stage_host_chunk(host)
+        with span("serve.kv.reload_chunk", sid=sid, pages=len(lis)):
+            s = self.pool.seq(sid)
+            host = np.stack([self.codec.decode(s.offloaded[li])
+                             for li in lis])
+            t0 = time.perf_counter()
+            if self.layout is not None:
+                staged = self.layout.stage_host_chunk(host)
+            else:
+                staged = jax.device_put(host, self.device)
+            jax.block_until_ready(staged)
+            self.reload_wall_s.append(time.perf_counter() - t0)
+            store = self.pool.complete_reload(
+                sid, lis, LayerStackedPages(self.k_pages, self.v_pages),
+                staged=staged)
+            self.k_pages, self.v_pages = store.k, store.v
+            self._place_pages()
+            self._sync_page_counts(sid)
+        if self._settling:
+            self.settled_reload_pages += len(lis)
         else:
-            staged = jax.device_put(host, self.device)
-        jax.block_until_ready(staged)
-        self.reload_wall_s.append(time.perf_counter() - t0)
-        store = self.pool.complete_reload(
-            sid, lis, LayerStackedPages(self.k_pages, self.v_pages),
-            staged=staged)
-        self.k_pages, self.v_pages = store.k, store.v
-        self._place_pages()
-        self._sync_page_counts(sid)
+            self.drained_reload_pages += len(lis)
 
     def _io_offload_chunk(self, sid: str, lis: List[int]) -> None:
         """Physically land one offload chunk: gather the device pages
         to host copies, then free the slots (copy-then-free step 2)."""
-        s = self.pool.seq(sid)
-        phys = np.asarray([s.pages[li] for li in lis], np.int64)
-        hk = np.asarray(self.k_pages[:, phys])     # [L, n, page, Hkv*hd]
-        hv = np.asarray(self.v_pages[:, phys])
-        self.pool.complete_offload(
-            sid, {li: self.codec.encode(np.stack([hk[:, i], hv[:, i]]))
-                  for i, li in enumerate(lis)})
-        self._sync_page_counts(sid)
+        with span("serve.kv.offload_chunk", sid=sid, pages=len(lis)):
+            s = self.pool.seq(sid)
+            phys = np.asarray([s.pages[li] for li in lis], np.int64)
+            hk = np.asarray(self.k_pages[:, phys])  # [L, n, page, Hkv*hd]
+            hv = np.asarray(self.v_pages[:, phys])
+            self.pool.complete_offload(
+                sid, {li: self.codec.encode(np.stack([hk[:, i], hv[:, i]]))
+                      for i, li in enumerate(lis)})
+            self._sync_page_counts(sid)
 
     def drain_transfers(self, max_chunks: Optional[int] = None) -> int:
         """Complete up to ``max_chunks`` queued transfer chunks (both
         directions, FIFO). run_round calls this with the per-round
         budget; the gateways call it from their idle loops so preloads
         progress even when nothing is decoding."""
-        return self.transfer.drain(self.clock.now(), max_chunks)
+        with span("serve.xfer.drain", chunks=max_chunks):
+            return self.transfer.drain(self.clock.now(), max_chunks)
 
     def flush_transfers(self) -> int:
         """Drain everything (tests / shutdown)."""
@@ -827,23 +846,26 @@ class PagedRealtimeEngine:
         while it was still queued (its arrival_time is the instant the
         utterance reached the gateway, preserving queue wait in TTFP).
         Works for turn 0 and later turns alike."""
-        prompt = np.asarray(prompt, np.int32)
-        if session_id not in self.sessions:
-            sess = self._prep_first_turn(session_id)
-            prompt = self._attach_prefix(sess, prompt)
-        else:
-            sess = self._prep_next_turn(session_id)
-        if request is not None:
-            sess.turn_arrival = min(sess.turn_arrival,
-                                    request.arrival_time)
-        slot = self.free_slot()
-        assert slot is not None, "no free decode slot"
-        req = self._make_request(sess, prompt, max_new_tokens,
-                                 request=request)
-        self.slot_state[slot] = PagedSlot(session_id, req, -1, [],
-                                          prompt=prompt)
-        self._sync_page_counts(session_id)
-        return slot
+        sess = self.sessions.get(session_id)
+        with span("serve.admit", sid=session_id,
+                  turn=sess.turn_index + 1 if sess else 0):
+            prompt = np.asarray(prompt, np.int32)
+            if sess is None:
+                sess = self._prep_first_turn(session_id)
+                prompt = self._attach_prefix(sess, prompt)
+            else:
+                sess = self._prep_next_turn(session_id)
+            if request is not None:
+                sess.turn_arrival = min(sess.turn_arrival,
+                                        request.arrival_time)
+            slot = self.free_slot()
+            assert slot is not None, "no free decode slot"
+            req = self._make_request(sess, prompt, max_new_tokens,
+                                     request=request)
+            self.slot_state[slot] = PagedSlot(session_id, req, -1, [],
+                                              prompt=prompt)
+            self._sync_page_counts(session_id)
+            return slot
 
     def _prep_first_turn(self, session_id: str) -> PagedSession:
         assert session_id not in self.sessions, \
@@ -868,7 +890,17 @@ class PagedRealtimeEngine:
         # pass must never pick the session being brought back as its own
         # victim.
         self.kv.pin(session_id)
-        stall = self.preloader.on_turn_ready(session_id, self.clock.now())
+        settled = self.settled_reload_pages
+        t0 = time.perf_counter()
+        with span("serve.kv.settle", sid=session_id,
+                  pages=self.kv.missing_blocks(session_id)):
+            self._settling = True
+            try:
+                stall = self.preloader.on_turn_ready(session_id,
+                                                     self.clock.now())
+            finally:
+                self._settling = False
+        settle_s = time.perf_counter() - t0
         # the accounting view (dram blocks), not the host-copy dict, is
         # the guard: under copy-then-free a saturated-pool session can
         # have its suffix still *offloading* (chunks queued, `offloaded`
@@ -898,9 +930,13 @@ class PagedRealtimeEngine:
         self.monitor.on_speech_end(session_id)
         self.monitor.on_turn_start(session_id, sess.turn_index)
         sess.turn_arrival = self.clock.now()
-        if stall > 0:
-            self.clock.tick(stall)          # on-path sync reload residual
+        if stall > 0 and not getattr(self.clock, "self_advancing", False):
+            # on-path sync reload residual, on a clock that only moves
+            # when charged; a wall clock already spent the settlement
+            self.clock.tick(stall)
         sess.reload_stall_s = stall
+        sess.reload_on_path_wall_s = (
+            settle_s if self.settled_reload_pages > settled else 0.0)
         _, sess.reload_off_path_s = self.preloader.pop_split(session_id)
         return sess
 
@@ -928,6 +964,7 @@ class PagedRealtimeEngine:
             req.max_new_tokens = max_new_tokens
         req.reload_stall_s = sess.reload_stall_s
         req.reload_off_path_s = sess.reload_off_path_s
+        req.reload_on_path_wall_s = sess.reload_on_path_wall_s
         req.prefix_hit_tokens = self._pending_hit.pop(sid, 0)
         sess.turn_stats.append({
             "turn": sess.turn_index,
@@ -1306,9 +1343,11 @@ class PagedRealtimeEngine:
         queued transfer chunks — this is where a speech-time preload
         physically lands while other sessions keep decoding
         (DESIGN.md §10)."""
-        if self.fused_step:
-            return self._run_round_fused(chunks)
-        return self._run_round_tokenwise(chunks)
+        with span("serve.round", slots=len(chunks),
+                  tokens=sum(chunks.values())):
+            if self.fused_step:
+                return self._run_round_fused(chunks)
+            return self._run_round_tokenwise(chunks)
 
     def _round_feeds(self, chunks: Dict[int, int]) -> Dict[int, tuple]:
         """The round's grants as token arrays: ``{slot: (sid, tokens)}``
@@ -1385,9 +1424,10 @@ class PagedRealtimeEngine:
         xfer_budget = self.transfer_chunks_per_round
         if xfer_budget > 0:
             xfer_budget -= self.drain_transfers(1)
-        feeds = self._round_feeds(chunks)
-        if feeds:
-            out, outs = self._run_chunk_rows(feeds)
+        with span("serve.round.feeds"):
+            feeds = self._round_feeds(chunks)
+        out, outs = self._run_chunk_rows(feeds) if feeds else (None, None)
+        with span("serve.round.sample"):
             for i, (sid, toks) in feeds.items():
                 s = self.slot_state[i]
                 sess = self.sessions[sid]
@@ -1469,86 +1509,88 @@ class PagedRealtimeEngine:
         for j in range(max(chunks.values(), default=0)):
             if xfer_budget > 0:
                 xfer_budget -= self.drain_transfers(1)
-            feeds = {}
-            for i, c in chunks.items():
-                s = self.slot_state[i]
-                if s is None or not s.request.is_live():
-                    continue
-                r = s.request
-                if r.phase == Phase.PREFILL:
-                    if j < c and r.prefilled < r.prompt_len:
-                        feeds[i] = (s.session_id,
-                                    int(s.prompt[r.prefilled]))
-                elif j == 0 and c > 0 \
-                        and r.generated < r.max_new_tokens:
-                    feeds[i] = (s.session_id, s.pending_token)
-            if not feeds:
-                break
-            for i in list(feeds):
-                s = self.slot_state[i]
-                sess = self.sessions[s.session_id]
-                try:
-                    self._grow(s.session_id, sess.kv_len + 1)
-                    self._ensure_writable(s.session_id)
-                except OutOfPages:
-                    # mid-chunk allocation failure: admission accounted
-                    # blocks that interaction events (speech protection,
-                    # a barge-in trim re-pinning pressure elsewhere)
-                    # made unreclaimable by the time this sub-batch
-                    # allocates. Hold the slot — it retries next round
-                    # when pressure drains; scheduling moves WHEN tokens
-                    # appear, never WHICH (§5.2), so holding is safe.
-                    del feeds[i]
-                    self.pressure_holds += 1
-                    continue
-                # best-effort lookahead, hoisted to once per slot per
-                # round (ISSUE 5 satellite): cover the slot's remaining
-                # grant plus the page past it, so the boundary token
-                # never waits on allocation/eviction (these are the
-                # in-flight pages a barge-in trims)
-                if i not in lookahead_done:
-                    lookahead_done.add(i)
+            with span("serve.round.feeds"):
+                feeds = {}
+                for i, c in chunks.items():
+                    s = self.slot_state[i]
+                    if s is None or not s.request.is_live():
+                        continue
                     r = s.request
-                    rest = min(chunks[i] - j,
-                               r.prompt_len - r.prefilled) \
-                        if r.phase == Phase.PREFILL else 1
-                    self._grow(s.session_id,
-                               sess.kv_len + rest + self.page_size,
-                               best_effort=True)
+                    if r.phase == Phase.PREFILL:
+                        if j < c and r.prefilled < r.prompt_len:
+                            feeds[i] = (s.session_id,
+                                        int(s.prompt[r.prefilled]))
+                    elif j == 0 and c > 0 \
+                            and r.generated < r.max_new_tokens:
+                        feeds[i] = (s.session_id, s.pending_token)
+                if not feeds:
+                    break
+                for i in list(feeds):
+                    s = self.slot_state[i]
+                    sess = self.sessions[s.session_id]
+                    try:
+                        self._grow(s.session_id, sess.kv_len + 1)
+                        self._ensure_writable(s.session_id)
+                    except OutOfPages:
+                        # mid-chunk allocation failure: admission accounted
+                        # blocks that interaction events (speech protection,
+                        # a barge-in trim re-pinning pressure elsewhere)
+                        # made unreclaimable by the time this sub-batch
+                        # allocates. Hold the slot — it retries next round
+                        # when pressure drains; scheduling moves WHEN tokens
+                        # appear, never WHICH (§5.2), so holding is safe.
+                        del feeds[i]
+                        self.pressure_holds += 1
+                        continue
+                    # best-effort lookahead, hoisted to once per slot per
+                    # round: cover the slot's remaining
+                    # grant plus the page past it, so the boundary token
+                    # never waits on allocation/eviction (these are the
+                    # in-flight pages a barge-in trims)
+                    if i not in lookahead_done:
+                        lookahead_done.add(i)
+                        r = s.request
+                        rest = min(chunks[i] - j,
+                                   r.prompt_len - r.prefilled) \
+                            if r.phase == Phase.PREFILL else 1
+                        self._grow(s.session_id,
+                                   sess.kv_len + rest + self.page_size,
+                                   best_effort=True)
             if not feeds:
                 continue                     # everything held this round
             out = self._run_rows(feeds)
-            for i in feeds:
-                s = self.slot_state[i]
-                sess = self.sessions[s.session_id]
-                sess.kv_len += 1
-                sess.token_ids.append(int(feeds[i][1]))
-                r = s.request
-                tok = int(np.argmax(out[i]))
-                if r.phase == Phase.PREFILL:
-                    r.prefilled += 1
-                    if r.done_prefill:
-                        # the last prompt token's logits are the first
-                        # output token — same contract as the sync paths
-                        r.phase = Phase.DECODE
-                        r.first_output_time = self.clock.now()
+            with span("serve.round.sample"):
+                for i in feeds:
+                    s = self.slot_state[i]
+                    sess = self.sessions[s.session_id]
+                    sess.kv_len += 1
+                    sess.token_ids.append(int(feeds[i][1]))
+                    r = s.request
+                    tok = int(np.argmax(out[i]))
+                    if r.phase == Phase.PREFILL:
+                        r.prefilled += 1
+                        if r.done_prefill:
+                            # the last prompt token's logits are the first
+                            # output token — same contract as the sync paths
+                            r.phase = Phase.DECODE
+                            r.first_output_time = self.clock.now()
+                            s.pending_token = tok
+                            s.tokens.append(tok)
+                            sess.turn_stats[-1]["ttft_s"] = \
+                                self.clock.now() - sess.turn_arrival
+                            events[i].append(("token", tok))
+                        else:
+                            events[i].append(("prefill", r.prefilled))
+                    else:
+                        r.generated += 1
                         s.pending_token = tok
-                        s.tokens.append(tok)
-                        sess.turn_stats[-1]["ttft_s"] = \
-                            self.clock.now() - sess.turn_arrival
-                        events[i].append(("token", tok))
-                    else:
-                        events[i].append(("prefill", r.prefilled))
-                else:
-                    r.generated += 1
-                    s.pending_token = tok
-                    if r.generated < r.max_new_tokens:
-                        s.tokens.append(tok)
-                        events[i].append(("token", tok))
-                    else:
-                        r.state = RequestState.FINISHED
-                        self._close_turn(i, aborted=False)
-                        events[i].append(("finished", r.generated))
+                        if r.generated < r.max_new_tokens:
+                            s.tokens.append(tok)
+                            events[i].append(("token", tok))
+                        else:
+                            r.state = RequestState.FINISHED
+                            self._close_turn(i, aborted=False)
+                            events[i].append(("finished", r.generated))
         if xfer_budget > 0:
             self.drain_transfers(xfer_budget)
         return events
@@ -1556,19 +1598,28 @@ class PagedRealtimeEngine:
     def _run_rows(self, feeds: Dict[int, tuple]) -> Dict[int, np.ndarray]:
         """Run one compiled step with `feeds[row] = (sid, token)`; other
         rows are padded to the scratch page. Returns per-row logits."""
-        rows: List[Optional[tuple]] = [None] * self.slots
-        tokens = np.zeros((self.slots,), np.int32)
-        for i, (sid, tok) in feeds.items():
-            rows[i] = (sid, self.sessions[sid].kv_len)
-            tokens[i] = tok
-        tabs: BatchTables = assemble(self.pool, rows, self.pages_per_seq,
-                                     self.scratch_page)
-        logits, self.k_pages, self.v_pages = self._step_fn(
-            self.params, jnp.asarray(tokens),
-            jnp.asarray(tabs.positions), self.k_pages, self.v_pages,
-            jnp.asarray(tabs.block_tables), jnp.asarray(tabs.seq_lens),
-            jnp.asarray(tabs.write_page), jnp.asarray(tabs.write_slot))
-        logits = np.asarray(logits)
+        with span("serve.round.tables"):
+            rows: List[Optional[tuple]] = [None] * self.slots
+            tokens = np.zeros((self.slots,), np.int32)
+            for i, (sid, tok) in feeds.items():
+                rows[i] = (sid, self.sessions[sid].kv_len)
+                tokens[i] = tok
+            tabs: BatchTables = assemble(self.pool, rows,
+                                         self.pages_per_seq,
+                                         self.scratch_page)
+            args = (self.params, jnp.asarray(tokens),
+                    jnp.asarray(tabs.positions), self.k_pages,
+                    self.v_pages, jnp.asarray(tabs.block_tables),
+                    jnp.asarray(tabs.seq_lens),
+                    jnp.asarray(tabs.write_page),
+                    jnp.asarray(tabs.write_slot))
+        with span("serve.round.launch", B=self.slots, Q=1,
+                  valid=len(feeds)):
+            logits, self.k_pages, self.v_pages = self._step_fn(*args)
+        self.launch_valid_tokens += len(feeds)
+        self.launch_grid_tokens += self.slots
+        with span("serve.round.logits"):
+            logits = np.asarray(logits)
         if self.logit_tap is not None:
             for i, (sid, _) in feeds.items():
                 self.logit_tap(sid, logits[i])
@@ -1582,29 +1633,38 @@ class PagedRealtimeEngine:
         each row's per-position argmaxes when the engine runs the
         speculative verify variant (None on the non-spec plane)."""
         q_tokens = _q_bucket(max(len(t) for _, t in feeds.values()))
-        rows: List[Optional[tuple]] = [None] * self.slots
-        tokens = np.zeros((self.slots, q_tokens), np.int32)
-        for i, (sid, toks) in feeds.items():
-            rows[i] = (sid, self.sessions[sid].kv_len, len(toks))
-            tokens[i, :len(toks)] = toks
-        tabs: FusedBatchTables = assemble_fused(
-            self.pool, rows, q_tokens, self.pages_per_seq,
-            self.scratch_page)
-        args = (self.params, jnp.asarray(tokens),
-                jnp.asarray(tabs.positions), self.k_pages, self.v_pages,
-                jnp.asarray(tabs.block_tables), jnp.asarray(tabs.q_start),
-                jnp.asarray(tabs.q_lens), jnp.asarray(tabs.write_pages),
-                jnp.asarray(tabs.write_slots))
-        if self._spec_fn is not None:
-            logits, outs, self.k_pages, self.v_pages = \
-                self._spec_fn(*args)
-            outs = np.asarray(outs)
-            out_rows = {i: outs[i] for i in feeds}
-        else:
-            logits, self.k_pages, self.v_pages = self._fused_fn(*args)
-            out_rows = None
+        valid = sum(len(t) for _, t in feeds.values())
+        with span("serve.round.tables"):
+            rows: List[Optional[tuple]] = [None] * self.slots
+            tokens = np.zeros((self.slots, q_tokens), np.int32)
+            for i, (sid, toks) in feeds.items():
+                rows[i] = (sid, self.sessions[sid].kv_len, len(toks))
+                tokens[i, :len(toks)] = toks
+            tabs: FusedBatchTables = assemble_fused(
+                self.pool, rows, q_tokens, self.pages_per_seq,
+                self.scratch_page)
+            args = (self.params, jnp.asarray(tokens),
+                    jnp.asarray(tabs.positions), self.k_pages,
+                    self.v_pages, jnp.asarray(tabs.block_tables),
+                    jnp.asarray(tabs.q_start), jnp.asarray(tabs.q_lens),
+                    jnp.asarray(tabs.write_pages),
+                    jnp.asarray(tabs.write_slots))
+        with span("serve.round.launch", B=self.slots, Q=q_tokens,
+                  valid=valid):
+            if self._spec_fn is not None:
+                logits, outs, self.k_pages, self.v_pages = \
+                    self._spec_fn(*args)
+            else:
+                logits, self.k_pages, self.v_pages = self._fused_fn(*args)
         self.fused_launches += 1
-        logits = np.asarray(logits)
+        self.launch_valid_tokens += valid
+        self.launch_grid_tokens += self.slots * q_tokens
+        with span("serve.round.logits"):
+            out_rows = None
+            if self._spec_fn is not None:
+                outs = np.asarray(outs)
+                out_rows = {i: outs[i] for i in feeds}
+            logits = np.asarray(logits)
         if self.logit_tap is not None:
             for i, (sid, _) in feeds.items():
                 self.logit_tap(sid, logits[i])
