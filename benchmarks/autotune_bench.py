@@ -1,15 +1,16 @@
 """Kernel autotune sweep (DESIGN.md §16): tune the paged-attention
 tiling knobs per shape, round-trip the JSON cache, and report what was
 picked. Interpret-mode timings on CPU rank *relative* candidate cost
-(grid-step count dominates there exactly as launch overhead does on
-TPU); the roofline gate keeps a noisy timing from promoting a config
-the arithmetic-intensity model prices absurdly.
+(block count dominates there as launch overhead does on TPU); the
+roofline gate keeps a noisy timing from promoting a config the
+arithmetic-intensity model prices absurdly.
 
-The page=32 decode shape is the reproducibility probe: its static
-default is kv_block=16 (``_default_kv_block`` caps pow2 pages at a
-16-slot tile), while one grid step per whole page measurably wins in
-interpret mode — so a correct sweep reproducibly selects the
-non-default kv_block=32 (pinned by tests/test_autotune.py).
+The page=32 decode shape with a 16-page table is the reproducibility
+probe: the block rule (``kv_block_tokens``) caps a block at 128 tokens,
+four blocks a row there, while fewer, larger blocks measurably win in
+interpret mode — so a correct sweep reproducibly
+selects a non-default block larger than the rule's (pinned by
+tests/test_autotune.py).
 """
 from __future__ import annotations
 
@@ -24,7 +25,7 @@ SHAPES = [
     ("paged_attention",
      dict(B=4, Hq=4, Hkv=2, D=16, page=16, pps=4)),
     ("paged_attention",
-     dict(B=4, Hq=4, Hkv=2, D=16, page=32, pps=4)),   # non-default probe
+     dict(B=4, Hq=4, Hkv=2, D=16, page=32, pps=16)),  # non-default probe
     ("paged_prefill_attention",
      dict(B=4, Hq=4, Hkv=2, D=16, page=16, pps=4, Q=4)),
 ]

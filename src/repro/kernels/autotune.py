@@ -1,15 +1,18 @@
 """Kernel autotune harness (DESIGN.md §16).
 
-Sweeps the paged-attention tiling knobs (``kv_block`` sub-page tiles,
-``head_block`` KV heads per launch) per kernel shape, checks every
-candidate against the pure-jnp oracle, gates the pick with a
-roofline-style arithmetic-intensity model, and caches the winner in a
-JSON store keyed by (kernel, shape, backend). The cache is consulted at
-jit time by ``paged_attention``/``paged_prefill_attention`` — but ONLY
-after ``enable(path)`` loads it into this module's process-global
-state; with autotune disabled (the default) every lookup is a no-op and
-the kernels run their static defaults, so serving stays bit-exact
-unless explicitly opted in (the ``async_transfers=False`` pattern).
+Sweeps the paged-attention tiling knobs (``kv_block`` tokens of one
+block of whole pages, ``head_block`` KV heads per launch) per kernel
+shape, checks every candidate against the pure-jnp oracle, gates the
+pick with a roofline-style arithmetic-intensity model, and caches the
+winner in a JSON store keyed by (kernel, shape, backend). The cache is
+consulted at jit time by ``paged_attention``/``paged_prefill_attention``
+— but ONLY after ``enable(path)`` loads it into this module's
+process-global state; with autotune disabled (the default) every lookup
+is a no-op and the kernels run their block rule, so serving stays
+bit-exact unless explicitly opted in (the ``async_transfers=False``
+pattern).
+Timings taken on the CPU interpret path rank only the interpreter's
+costs; a pick is the chip's only if the sweep ran on the chip.
 
 Cache format (invalidation rules):
   {"__meta__": {"version": 1},
@@ -40,7 +43,7 @@ FORMAT_VERSION = 1
 # so TPU-ish absolutes are fine even when sweeping the CPU interpret
 # path.
 C_LAUNCH_US = 5.0        # fixed pallas_call dispatch cost
-C_STEP_US = 0.4          # per-grid-step overhead (DMA issue, control)
+C_STEP_US = 0.4          # per-block overhead (DMA starts, control)
 HBM_GB_S = 800.0         # KV stream bandwidth
 PEAK_GFLOPS = 50_000.0   # MXU peak
 
@@ -113,13 +116,15 @@ def save(path: Optional[str] = None) -> str:
 
 
 # ------------------------------------------------------------ the model
-def candidate_configs(page: int, Hkv: int) -> list:
-    """The sweep space for one shape: every kv_block dividing the page
-    (powers of two up to 128, plus whole-page and the static default),
-    crossed with head_block in {1, Hkv}."""
-    from repro.kernels.paged_attention import _default_kv_block
-    kvs = sorted({b for b in (8, 16, 32, 64, 128) if page % b == 0}
-                 | {page, _default_kv_block(page)})
+def candidate_configs(page: int, Hkv: int, *, D: int, pps: int,
+                      itemsize: int = 4) -> list:
+    """The sweep space for one shape: every block of a power-of-two
+    number of whole pages up to the block table, plus the whole table
+    and the block rule's pick, crossed with head_block in {1, Hkv}."""
+    from repro.kernels.paged_attention import kv_block_tokens
+    kvs = sorted({page << k for k in range(pps.bit_length())}
+                 | {page * pps, kv_block_tokens(page, Hkv * D, itemsize,
+                                                pps)})
     heads = sorted({Hkv} | ({1} if Hkv > 1 else set()))
     return [{"kv_block": kb, "head_block": hb}
             for kb in kvs for hb in heads]
@@ -129,13 +134,14 @@ def modeled_cost_us(*, B: int, Hkv: int, D: int, page: int, pps: int,
                     Q: int = 1, G: int = 1, kv_block: int,
                     head_block: int) -> float:
     """Arithmetic-intensity cost of one call under a candidate tiling:
-    launch dispatches + grid-step overheads + the KV byte stream over
-    HBM bandwidth + the attention flops at peak. Shared with
+    launch dispatches + per-block overheads (every block of every row,
+    as for rows at the context cap) + the KV byte stream over HBM
+    bandwidth + the attention flops at peak. Shared with
     ``benchmarks/roofline_report.py``'s framing: the bytes/flops terms
     are tiling-invariant, so the model ranks tilings purely by launch
-    and step overhead — exactly the knobs the sweep moves."""
+    and block overhead — exactly the knobs the sweep moves."""
     launches = Hkv // head_block
-    total_steps = B * Hkv * pps * (page // kv_block)
+    total_steps = launches * B * -(-pps * page // kv_block)
     kv_bytes = 2 * B * pps * page * Hkv * D * 4
     flops = 4 * B * Hkv * G * Q * pps * page * D
     return (launches * C_LAUNCH_US + total_steps * C_STEP_US
@@ -207,14 +213,14 @@ def sweep(kind: str, *, B: int, Hq: int, Hkv: int, D: int, page: int,
             walls.append(time.perf_counter() - t0)
         return statistics.median(walls) * 1e6
 
-    default = {"kv_block": pk._default_kv_block(page),
+    default = {"kv_block": pk.kv_block_tokens(page, Hkv * D, 4, pps),
                "head_block": Hkv}
     default_us = timed(default)
     assert default_us is not None, "default config failed correctness"
     default_model = modeled_cost_us(B=B, Hkv=Hkv, D=D, page=page,
                                     pps=pps, Q=Q, G=G, **default)
     best, best_us, best_model = dict(default), default_us, default_model
-    for cfg in candidate_configs(page, Hkv):
+    for cfg in candidate_configs(page, Hkv, D=D, pps=pps):
         if cfg == default:
             continue
         model_us = modeled_cost_us(B=B, Hkv=Hkv, D=D, page=page,

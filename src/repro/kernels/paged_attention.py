@@ -2,21 +2,19 @@
 
 Queries attend over a paged KV cache addressed through per-sequence
 block tables. This is the TPU-native re-think of vLLM-style CUDA paged
-attention (DESIGN.md §3): instead of warp-level gather, each grid step
-DMAs one KV tile HBM→VMEM, selected by a scalar-prefetched block table
-(``PrefetchScalarGridSpec``), and folds it into an online-softmax
-accumulator.
+attention (DESIGN.md §3): instead of warp-level gather, the kernel
+copies whole pages HBM→VMEM with its own DMAs, chosen by the
+scalar-prefetched block table, and folds each block of pages into an
+online-softmax accumulator.
 
 Page layout: the store is ``[P, page, Hkv*D]`` — each page is a
 ``[page, Hkv*D]`` row block with KV head ``h`` in lanes
-``[h*D, (h+1)*D)``. A grid step reads the ``(kv_block, D)`` tile of one
-head, which Mosaic accepts when ``D % 128 == 0`` (or ``Hkv == 1``): the
-tile's minor dims are then lane-aligned. (A ``[P, page, Hkv, D]`` store
-tiled ``(kv_block, 1, D)`` is refused — a one-head slice of the
-second-minor dim is neither 8-divisible nor the whole dim.) Callers may
-pass the 4-D ``[P, page, Hkv, D]`` view; it is reshaped to the merged
-store, which is free for a row-major array but a relayout copy on the
-chip, so the engine keeps its store merged.
+``[h*D, (h+1)*D)``. One DMA moves one page for every head; the kernel
+then reads head ``h`` as a lane slice, which Mosaic accepts when
+``D % 128 == 0`` (or ``Hkv == 1``). Callers may pass the 4-D
+``[P, page, Hkv, D]`` view; it is reshaped to the merged store, which
+is free for a row-major array but a relayout copy on the chip, so the
+engine keeps its store merged.
 
 ``paged_prefill_attention`` is the one kernel (DESIGN.md §11): each
 batch row carries a *chunk* of Q consecutive query tokens (a prefill
@@ -28,8 +26,16 @@ own online-softmax accumulator, so one launch serves a whole mixed
 prefill+decode token budget (and the spec-decode verify step, DESIGN.md
 §16). ``paged_attention`` is its Q=1 case.
 
-Grid: (B, Hkv, pages_per_seq * page/kv_block) — KV tiles innermost,
-accumulators in VMEM.
+Grid: ``(B,)``, one step per batch row. A *block* is ``kv_block / page``
+consecutive block-table pages, all KV heads. Step ``b`` loops over the
+row's live blocks only — those that start before ``q_start + q_lens``;
+a row with ``q_lens == 0`` has none — so the work follows the live KV,
+not the context cap. K and V blocks are double-buffered in VMEM: while
+block ``i`` is computed the copies of block ``i+1`` run, and the last
+block of a row starts the copies of the next live row's first block.
+Per block, a static loop over the KV heads updates one accumulator
+``[G*Q, D]`` (plus ``m``, ``l``) per head in VMEM. Scores, softmax and
+accumulators are f32; K and V are the stored values, upcast in VMEM.
 
 Both entry points also run as one *shard* of a tensor-sharded page
 store (DESIGN.md §9): when the 'model' mesh axis splits each page's
@@ -42,13 +48,14 @@ additionally emits the online-softmax running max ``m`` and denominator
 shards (the standard flash-merge: weight each shard's normalized output
 by ``l_s * exp(m_s - max_s m_s)``).
 
-Tiling knobs (DESIGN.md §16): ``kv_block`` splits each page into
-``page / kv_block`` grid steps (arithmetic-identical at any legal value,
-because the online softmax folds tiles in the same position order);
-``head_block`` caps the KV heads per launch, splitting the head axis
-across multiple ``pallas_call``s whose outputs concatenate (exact, by
-per-head softmax independence). Both default to a static heuristic and
-are overridden per (shape, backend) by the autotune cache when
+Tiling knobs (DESIGN.md §16): ``kv_block`` is the tokens of one block,
+a whole number of pages (arithmetic-identical up to rounding at any
+legal value, because the online softmax folds blocks in position
+order); ``head_block`` caps the KV heads per launch, splitting the head
+axis across multiple ``pallas_call``s whose outputs concatenate (exact,
+by per-head softmax independence). ``kv_block`` defaults to the block
+rule ``kv_block_tokens`` and ``head_block`` to all heads; the autotune
+cache overrides both per (shape, backend) when
 ``repro.kernels.autotune.enable()`` has loaded one — callers that pass
 explicit values bypass the cache entirely.
 """
@@ -64,19 +71,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-
-def _default_kv_block(page: int) -> int:
-    """Static tile heuristic: whole-page tiles up to 16 slots (a whole
-    page is always a legal block); larger 16-divisible pages default to
-    16-slot sub-tiles — the bf16 sublane tile — and the autotune sweep
-    overrides per shape."""
-    return page if (page <= 16 or page % 16 != 0) else 16
+BLOCK_TOKENS = 128            # KV tokens a block aims at
+BLOCK_VMEM_BYTES = 4 << 20    # K and V blocks, both buffers
 
 
-def _resolve(kind: str, kv_block, head_block, *, page: int, Hkv: int,
+def kv_block_tokens(page: int, HD: int, itemsize: int, pps: int) -> int:
+    """The block rule: whole pages, about ``BLOCK_TOKENS`` tokens, with
+    the double-buffered K and V blocks within ``BLOCK_VMEM_BYTES`` and
+    no more pages than a block table has. Pages are stacked only when
+    they are whole 8-row f32 tiles, so the stacked block is one
+    ``[tokens, D]`` operand; an unaligned page is a block alone."""
+    if page % 8:
+        return page
+    ppb = min(max(1, BLOCK_TOKENS // page),
+              max(1, BLOCK_VMEM_BYTES // (4 * page * HD * itemsize)),
+              pps)
+    return ppb * page
+
+
+def _resolve(kind: str, kv_block, head_block, *, itemsize: int,
              dims: dict) -> tuple:
     """Fill unset tiling knobs from the autotune cache (a no-op unless
-    ``autotune.enable()`` loaded one), else the static defaults."""
+    ``autotune.enable()`` loaded one), else the block rule and all
+    heads. ``dims`` holds at least ``page``, ``Hkv``, ``D`` and
+    ``pps``."""
+    page, Hkv = dims["page"], dims["Hkv"]
     if kv_block is None or head_block is None:
         from repro.kernels import autotune
         tuned = autotune.lookup(kind, autotune.shape_key(**dims))
@@ -86,29 +105,28 @@ def _resolve(kind: str, kv_block, head_block, *, page: int, Hkv: int,
             if head_block is None:
                 head_block = tuned.get("head_block")
     if kv_block is None:
-        kv_block = _default_kv_block(page)
+        kv_block = kv_block_tokens(page, Hkv * dims["D"], itemsize,
+                                   dims["pps"])
     if head_block is None:
         head_block = Hkv
-    assert page % kv_block == 0, (page, kv_block)
+    assert kv_block % page == 0, (page, kv_block)
     assert Hkv % head_block == 0, (Hkv, head_block)
     return kv_block, head_block
 
 
 def _check_tiles(kv_pages, kv_block: int, Hkv: int, D: int) -> None:
-    """Refuse, with the reason, a tiling Mosaic would refuse: the
-    ``(kv_block, D)`` tile needs lane-aligned heads and whole packed
-    sublane tiles (8 rows of 32-bit, 16 of 16-bit) unless it spans the
-    whole page."""
+    """Refuse, with the reason, a tiling Mosaic would refuse: a head's
+    lane slice of the store must be lane-aligned, and stacked pages
+    must each be whole 8-row f32 tiles."""
     page = kv_pages.shape[1]
-    rows = 8 * 4 // kv_pages.dtype.itemsize
     if Hkv > 1 and D % 128:
         raise ValueError(
-            f"head_dim {D} is not a multiple of 128: a one-head tile of "
+            f"head_dim {D} is not a multiple of 128: a one-head slice of "
             f"the [P, page, Hkv*D] store is not lane-aligned on the chip")
-    if kv_block != page and kv_block % rows:
+    if kv_block != page and page % 8:
         raise ValueError(
-            f"kv_block {kv_block} is not a multiple of the {rows}-row "
-            f"{kv_pages.dtype} tile (page {page})")
+            f"a kv_block of {kv_block // page} pages stacks pages of "
+            f"{page} rows, which are not whole 8-row tiles")
 
 
 def _merged(kv_pages, D: int):
@@ -118,54 +136,115 @@ def _merged(kv_pages, D: int):
     return kv_pages
 
 
-def _kernel(block_tables, q_start, q_lens, q_ref, k_ref, v_ref, *refs,
-            kv_block: int, bpp: int, total_steps: int, scale: float,
-            pos_stride: int, pos_offset: int, stats: bool, Q: int):
+def _kernel(block_tables, nblocks, next_row, q_start, q_lens, q_ref,
+            k_hbm, v_hbm, *refs, ppb: int, scale: float, pos_stride: int,
+            pos_offset: int, stats: bool, Q: int):
     if stats:
-        o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref = refs
+        o_ref, m_out_ref, l_out_ref, *scratch = refs
     else:
-        o_ref, acc_ref, m_ref, l_ref = refs
+        o_ref, *scratch = refs
+    k_buf, v_buf, k_sem, v_sem, state, acc_ref, m_ref, l_ref = scratch
+    B, pps = block_tables.shape
+    _, _, page, HD = k_buf.shape
+    Hkv, N, D = acc_ref.shape
+    T = ppb * page
     b = pl.program_id(0)
-    p = pl.program_id(2)
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    @pl.when(b == 0)
+    def _first_row():
+        state[0] = 0                  # buffer of the row's first block
+        state[1] = 0                  # its copies already started
 
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def copies(row, blk, slot, wait_only=False):
+        """Start (or wait for) the K and V copies of block ``blk`` of
+        ``row`` into buffer ``slot``. A wait needs only the shapes; a
+        block running past the table re-reads its last page, whose
+        positions the mask then drops."""
+        def page_copies(j, carry):
+            pid = 0 if wait_only else block_tables[
+                row, jnp.minimum(blk * ppb + j, pps - 1)]
+            for hbm, buf, sem in ((k_hbm, k_buf, k_sem),
+                                  (v_hbm, v_buf, v_sem)):
+                cp = pltpu.make_async_copy(hbm.at[pid], buf.at[slot, j],
+                                           sem.at[slot])
+                if wait_only:
+                    cp.wait()
+                else:
+                    cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, ppb, page_copies, 0)
+
+    n = nblocks[b]
     start = q_start[b]
     nq = q_lens[b]
-    seq_len = start + nq                 # post-write attention length
-    base = (p // bpp) * pos_stride + (p % bpp) * kv_block + pos_offset
 
-    @pl.when(base < seq_len)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)              # [G*Q, D]
-        k = k_ref[0].astype(jnp.float32)                 # [kv_block, D]
-        v = v_ref[0].astype(jnp.float32)                 # [kv_block, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        kv_pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    @pl.when(n > 0)
+    def _row():
+        @pl.when(state[1] == 0)
+        def _fetch_first():
+            copies(b, 0, state[0])
+
         # query rows are (g, t) pairs, t minor: row r is token r % Q
-        t_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % Q
-        mask = (kv_pos <= start + t_idx) & (t_idx < nq)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]                               # [G*Q, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + pexp @ v
-        m_ref[...] = m_new
+        t_idx = jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (N, T), 0), Q)
+        # a padding token's limit lies before every position
+        limit = jnp.where(t_idx < nq, start + t_idx, pos_offset - 1)
+        r = jax.lax.broadcasted_iota(jnp.int32, (N, T), 1)
+        if pos_stride != page:        # a shard's slots of each page
+            r = jax.lax.div(r, page) * pos_stride + jax.lax.rem(r, page)
 
-    @pl.when(p == total_steps - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
-        if stats:
-            m_out_ref[0, 0] = m_ref[...]
-            l_out_ref[0, 0] = l_ref[...]
+        def block(i, slot):
+            other = 1 - slot
+
+            @pl.when(i + 1 < n)
+            def _prefetch():
+                copies(b, i + 1, other)
+
+            @pl.when(i + 1 == n)
+            def _prefetch_next_row():
+                nb = next_row[b]
+
+                @pl.when(nb < B)
+                def _():
+                    copies(nb, 0, other)
+                state[0] = other
+                state[1] = (nb < B).astype(jnp.int32)
+
+            copies(b, i, slot, wait_only=True)
+            mask = (i * ppb * pos_stride + pos_offset + r) <= limit
+            for h in range(Hkv):
+                lanes = pl.ds(h * D, D)
+                q = q_ref[0, h].astype(jnp.float32)           # [N, D]
+                k = k_buf[slot, :, :, lanes].astype(jnp.float32) \
+                    .reshape(T, D)
+                v = v_buf[slot, :, :, lanes].astype(jnp.float32) \
+                    .reshape(T, D)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ()))) * scale   # [N, T]
+                s = jnp.where(mask, s, NEG_INF)
+                m_prev = m_ref[h]                             # [N, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+                acc_ref[h] = acc_ref[h] * alpha + p @ v
+                m_ref[h] = m_new
+            return other
+
+        jax.lax.fori_loop(0, n, block, state[0])
+
+    denom = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    if stats:
+        m_out_ref[0] = m_ref[...]
+        l_out_ref[0] = l_ref[...]
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
@@ -186,11 +265,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     """
     B, Hq, D = q.shape
     k_pages, v_pages = _merged(k_pages, D), _merged(v_pages, D)
-    Hkv = k_pages.shape[2] // D
+    _, page, HD = k_pages.shape
+    Hkv = HD // D
     kv_block, head_block = _resolve(
-        "paged_attention", kv_block, head_block, page=k_pages.shape[1],
-        Hkv=Hkv, dims=dict(B=B, Hq=Hq, Hkv=Hkv, D=D, page=k_pages.shape[1],
-                           pps=block_tables.shape[1]))
+        "paged_attention", kv_block, head_block,
+        itemsize=k_pages.dtype.itemsize,
+        dims=dict(B=B, Hq=Hq, Hkv=Hkv, D=D, page=page,
+                  pps=block_tables.shape[1]))
     out = paged_prefill_attention(
         q[:, None], k_pages, v_pages, block_tables, seq_lens - 1,
         jnp.ones_like(seq_lens), pos_stride=pos_stride,
@@ -199,6 +280,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     if return_stats:
         return tuple(x[:, 0] for x in out)
     return out[:, 0]
+
+
+def live_blocks(q_start, q_lens, *, block_span: int, nblk: int,
+                pos_offset: int = 0):
+    """Per row, the blocks that start before ``q_start + q_lens``
+    (none for a ``q_lens == 0`` row), at most ``nblk``. A block of
+    ``block_span`` positions starts at ``i * block_span + pos_offset``.
+    Works on numpy and jax arrays alike."""
+    past = (q_start + q_lens - pos_offset).clip(0, None)
+    n = ((past + block_span - 1) // block_span).clip(None, nblk)
+    return n * (q_lens > 0)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_start,
@@ -226,12 +318,12 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_start,
     (fully-masked rows report ``m = NEG_INF`` — a finite, hugely
     negative sentinel — so merge weights vanish without NaNs).
 
-    ``kv_block`` (divides ``page``) sizes the per-grid-step KV tile;
+    ``kv_block`` (a multiple of ``page``) is the tokens of one block;
     ``head_block`` (divides ``Hkv``) splits the launch over the KV-head
     axis. Unset knobs come from the autotune cache when enabled, else
-    static defaults. Any legal values are output-identical. Compiled
-    for the chip (``interpret=False``) the tiling must be one Mosaic
-    accepts (``_check_tiles``).
+    the block rule and all heads. Any legal values agree up to
+    rounding. Compiled for the chip (``interpret=False``) the tiling
+    must be one Mosaic accepts (``_check_tiles``).
     """
     B, Q, Hq, D = q.shape
     k_pages, v_pages = _merged(k_pages, D), _merged(v_pages, D)
@@ -242,9 +334,10 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_start,
     if pos_stride is None:
         pos_stride = page
     kv_block, head_block = _resolve(
-        "paged_prefill_attention", kv_block, head_block, page=page,
-        Hkv=Hkv, dims=dict(B=B, Q=Q, Hq=Hq, Hkv=Hkv, D=D, page=page,
-                           pps=pages_per_seq))
+        "paged_prefill_attention", kv_block, head_block,
+        itemsize=k_pages.dtype.itemsize,
+        dims=dict(B=B, Q=Q, Hq=Hq, Hkv=Hkv, D=D, page=page,
+                  pps=pages_per_seq))
     if head_block < Hkv:
         # split the KV-head axis into independent launches; exact
         # because each head's softmax never mixes with another's
@@ -263,49 +356,61 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_start,
         return jnp.concatenate(parts, axis=2)
     if not interpret:
         _check_tiles(k_pages, kv_block, Hkv, D)
-    bpp = page // kv_block
-    total_steps = pages_per_seq * bpp
-    grid = (B, Hkv, total_steps)
+    ppb = kv_block // page
+    nblocks = live_blocks(q_start, q_lens, block_span=ppb * pos_stride,
+                          nblk=-(-pages_per_seq // ppb),
+                          pos_offset=pos_offset).astype(jnp.int32)
+    # the first live row after each row (B past the last)
+    live_row = jnp.where(nblocks > 0, jnp.arange(B, dtype=jnp.int32), B)
+    next_row = jax.lax.cummin(
+        jnp.append(live_row[1:], jnp.int32(B)), reverse=True)
     kernel = functools.partial(
-        _kernel, kv_block=kv_block, bpp=bpp, total_steps=total_steps,
-        scale=1.0 / math.sqrt(D), pos_stride=pos_stride,
+        _kernel, ppb=ppb, scale=1.0 / math.sqrt(D), pos_stride=pos_stride,
         pos_offset=pos_offset, stats=return_stats, Q=Q)
     # [B, Q, (Hkv, G), D] -> [B, Hkv, G*Q, D]: rows are (g, t), t minor,
     # so the kernel recovers the token index as row % Q
     N = G * Q
     qg = jnp.moveaxis(q.reshape(B, Q, Hkv, G, D), 1, 3) \
         .reshape(B, Hkv, N, D)
-    row_map = lambda b, h, p, bt, qs, ql: (b, h, 0, 0)   # noqa: E731
-    out_specs = pl.BlockSpec((1, 1, N, D), row_map)
+    row_map = lambda b, *_: (b, 0, 0, 0)                 # noqa: E731
+    out_specs = pl.BlockSpec((1, Hkv, N, D), row_map)
     out_shape = jax.ShapeDtypeStruct((B, Hkv, N, D), q.dtype)
     if return_stats:
         # stats keep a unit lane dim: a (N, 1) block is the whole of the
         # array's minor two dims, which Mosaic accepts
-        stat_spec = pl.BlockSpec((1, 1, N, 1), row_map)
+        stat_spec = pl.BlockSpec((1, Hkv, N, 1), row_map)
         stat_shape = jax.ShapeDtypeStruct((B, Hkv, N, 1), jnp.float32)
         out_specs = [out_specs, stat_spec, stat_spec]
         out_shape = [out_shape, stat_shape, stat_shape]
-    kv_spec = pl.BlockSpec((1, kv_block, D),
-                           lambda b, h, p, bt, qs, ql:
-                           (bt[b, p // bpp], p % bpp, h))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, 1, N, D), row_map), kv_spec, kv_spec],
+        num_scalar_prefetch=5,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, Hkv, N, D), row_map), any_spec,
+                  any_spec],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((N, D), jnp.float32),
-            pltpu.VMEM((N, 1), jnp.float32),
-            pltpu.VMEM((N, 1), jnp.float32),
+            pltpu.VMEM((2, ppb, page, HD), k_pages.dtype),
+            pltpu.VMEM((2, ppb, page, HD), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((Hkv, N, D), jnp.float32),
+            pltpu.VMEM((Hkv, N, 1), jnp.float32),
+            pltpu.VMEM((Hkv, N, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        # rows run in order: a row's last block prefetches the next's
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(block_tables, q_start, q_lens, qg, k_pages, v_pages)
+    )(block_tables, nblocks, next_row, q_start, q_lens, qg, k_pages,
+      v_pages)
 
     def unpack(x):                        # [B, Hkv, G*Q, ...] -> token-major
         tail = x.shape[3:]

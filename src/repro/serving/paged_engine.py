@@ -53,8 +53,8 @@ from repro.core.preload import SpeechPreloader
 from repro.core.scheduler import SchedulerConfig, UrgencyScheduler
 from repro.core.session import Phase, Request, RequestState
 from repro.core.transfer_engine import MIGRATE, TransferEngine
-from repro.kernels.paged_attention import paged_attention, \
-    paged_prefill_attention
+from repro.kernels.paged_attention import kv_block_tokens, live_blocks, \
+    paged_attention, paged_prefill_attention
 from repro.kvcache.paged import OutOfPages, PagedPool
 from repro.kvcache.quant import KVWireCodec
 from repro.models import init_cache, prefill
@@ -345,6 +345,9 @@ class PagedRealtimeEngine:
         self.k_pages = jnp.zeros(shape, dtype, device=device)
         self.v_pages = jnp.zeros(shape, dtype, device=device)
         self._place_pages()
+        # the attention kernel's KV block (its block rule at this store)
+        self._attn_block = kv_block_tokens(page_size, shape[-1],
+                                           dtype.itemsize, pages_per_seq)
         bytes_per_token = 2 * cfg.num_layers * cfg.num_kv_heads * hd \
             * dtype.itemsize
         self.kv = kv or KVManager(
@@ -450,6 +453,11 @@ class PagedRealtimeEngine:
         # (slots x Q bucket) the step computed
         self.launch_valid_tokens = 0
         self.launch_grid_tokens = 0
+        # attention KV blocks of every launch: those the kernel fetched
+        # and computed (each row's blocks up to its length), and the
+        # grid a fixed slots x context-cap tiling would step through
+        self.attn_blocks_live = 0
+        self.attn_blocks_grid = 0
         # reload pages landed inside a turn-start settlement (on the
         # turn's path) and by transfer drains (off it)
         self.settled_reload_pages = 0
@@ -1595,6 +1603,15 @@ class PagedRealtimeEngine:
             self.drain_transfers(xfer_budget)
         return events
 
+    def _count_attn_blocks(self, q_start, q_lens) -> None:
+        """Add one launch's attention blocks, from the kernel's own
+        ``q_start``/``q_lens`` rows (a decode launch's padding rows
+        attend over one slot, so each counts one block)."""
+        nblk = -(-self.max_context // self._attn_block)
+        self.attn_blocks_live += int(live_blocks(
+            q_start, q_lens, block_span=self._attn_block, nblk=nblk).sum())
+        self.attn_blocks_grid += self.slots * nblk
+
     def _run_rows(self, feeds: Dict[int, tuple]) -> Dict[int, np.ndarray]:
         """Run one compiled step with `feeds[row] = (sid, token)`; other
         rows are padded to the scratch page. Returns per-row logits."""
@@ -1618,6 +1635,8 @@ class PagedRealtimeEngine:
             logits, self.k_pages, self.v_pages = self._step_fn(*args)
         self.launch_valid_tokens += len(feeds)
         self.launch_grid_tokens += self.slots
+        self._count_attn_blocks(tabs.seq_lens - 1,
+                                np.ones_like(tabs.seq_lens))
         with span("serve.round.logits"):
             logits = np.asarray(logits)
         if self.logit_tap is not None:
@@ -1659,6 +1678,7 @@ class PagedRealtimeEngine:
         self.fused_launches += 1
         self.launch_valid_tokens += valid
         self.launch_grid_tokens += self.slots * q_tokens
+        self._count_attn_blocks(tabs.q_start, tabs.q_lens)
         with span("serve.round.logits"):
             out_rows = None
             if self._spec_fn is not None:

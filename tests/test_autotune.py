@@ -6,13 +6,15 @@
   unswept shapes miss to the static defaults;
 - tiling exactness: any legal ``kv_block``/``head_block`` is
   output-identical (head_block splits bit-exactly by per-head softmax
-  independence; kv_block re-tiles the flash accumulation within
+  independence; kv_block re-blocks the flash accumulation within
   tolerance of the oracle);
+- the block rule (``kv_block_tokens``): whole pages, ~128 tokens,
+  capped by VMEM and by the block table;
 - the sweep is gated by the arithmetic-intensity model and reproducibly
-  selects the non-default kv_block=32 for the page=32 decode shape
-  (the probe ``benchmarks/autotune_bench.py`` reports);
+  selects a block larger than the rule's for the page=32, 16-page decode
+  shape (the probe ``benchmarks/autotune_bench.py`` reports);
 - ``_resolve`` consults the cache only for unset knobs and only while
-  enabled — disabled serving keeps the static defaults (bit-exact
+  enabled — disabled serving keeps the block rule (bit-exact
   spec_decode=0 control stays untouched).
 """
 import json
@@ -23,7 +25,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import autotune, ref
-from repro.kernels.paged_attention import (_default_kv_block, _resolve,
+from repro.kernels.paged_attention import (_resolve, kv_block_tokens,
                                            paged_attention,
                                            paged_prefill_attention)
 
@@ -64,22 +66,30 @@ def test_cache_key_carries_backend():
 
 
 def test_default_kv_block_heuristic():
-    # whole-page tiles up to 16 slots and for non-16-divisible pages;
-    # 16-slot lane sub-tiles otherwise
-    assert [_default_kv_block(p) for p in (4, 8, 16, 20, 24, 32, 64)] \
-        == [4, 8, 16, 20, 24, 16, 16]
+    # about 128 tokens of whole pages; a page that is not whole 8-row
+    # tiles, or one past 128 tokens, is a block alone
+    assert [kv_block_tokens(p, 256, 2, 128)
+            for p in (4, 8, 16, 20, 24, 32, 64, 256)] \
+        == [4, 128, 128, 20, 120, 128, 128, 256]
+    # the double-buffered K and V blocks stay within the VMEM budget
+    assert kv_block_tokens(16, 8192, 2, 128) == 64
+    assert kv_block_tokens(16, 32768, 2, 128) == 16
+    # and a block never outgrows the block table
+    assert kv_block_tokens(16, 256, 2, 3) == 48
 
 
 def test_resolve_disabled_uses_static_defaults():
     assert not autotune.enabled()
     dims = dict(B=2, Hq=4, Hkv=2, D=16, page=32, pps=4)
-    assert _resolve("paged_attention", None, None, page=32, Hkv=2,
-                    dims=dims) == (16, 2)
+    assert _resolve("paged_attention", None, None, itemsize=4,
+                    dims=dims) == (128, 2)
     # explicit knobs always win
-    assert _resolve("paged_attention", 32, 1, page=32, Hkv=2,
+    assert _resolve("paged_attention", 32, 1, itemsize=4,
                     dims=dims) == (32, 1)
-    with pytest.raises(AssertionError):
-        _resolve("paged_attention", 7, None, page=32, Hkv=2, dims=dims)
+    # a block is whole pages
+    for bad in (7, 16):
+        with pytest.raises(AssertionError):
+            _resolve("paged_attention", bad, None, itemsize=4, dims=dims)
 
 
 def test_resolve_consults_cache_only_for_unset_knobs(tmp_path):
@@ -87,16 +97,16 @@ def test_resolve_consults_cache_only_for_unset_knobs(tmp_path):
     dims = dict(B=2, Hq=4, Hkv=2, D=16, page=32, pps=4)
     skey = autotune.shape_key(**dims)
     autotune._STATE["cache"][autotune.cache_key("paged_attention", skey)] \
-        = {"kv_block": 8, "head_block": 1}
-    assert _resolve("paged_attention", None, None, page=32, Hkv=2,
-                    dims=dims) == (8, 1)
+        = {"kv_block": 64, "head_block": 1}
+    assert _resolve("paged_attention", None, None, itemsize=4,
+                    dims=dims) == (64, 1)
     # a set knob is never overridden; the other still fills from cache
-    assert _resolve("paged_attention", 32, None, page=32, Hkv=2,
+    assert _resolve("paged_attention", 32, None, itemsize=4,
                     dims=dims) == (32, 1)
-    # unswept shape: miss, static defaults
+    # unswept shape: miss, the block rule
     other = dict(dims, B=3)
-    assert _resolve("paged_attention", None, None, page=32, Hkv=2,
-                    dims=other) == (16, 2)
+    assert _resolve("paged_attention", None, None, itemsize=4,
+                    dims=other) == (128, 2)
     s = autotune.stats()
     assert s["hits"] >= 2 and s["misses"] >= 1
 
@@ -149,7 +159,8 @@ def test_backend_component_invalidates_across_backends(tmp_path):
 def test_kv_block_tilings_match_oracle():
     q, kp, vp, bt, sl = _decode_case(jax.random.PRNGKey(0))
     want = np.asarray(ref.paged_attention_ref(q, kp, vp, bt, sl))
-    for kv_block in (8, 16, 32):
+    # one page, two (a 3-page table leaves a tail block), the table
+    for kv_block in (32, 64, 96):
         got = paged_attention(q, kp, vp, bt, sl, interpret=True,
                               kv_block=kv_block)
         np.testing.assert_allclose(np.asarray(got), want,
@@ -162,9 +173,9 @@ def test_head_block_split_is_bit_exact():
     head-split launch must reproduce the whole launch bit for bit."""
     q, kp, vp, bt, sl = _decode_case(jax.random.PRNGKey(1))
     whole = paged_attention(q, kp, vp, bt, sl, interpret=True,
-                            kv_block=16, head_block=2)
+                            kv_block=64, head_block=2)
     split = paged_attention(q, kp, vp, bt, sl, interpret=True,
-                            kv_block=16, head_block=1)
+                            kv_block=64, head_block=1)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(split))
 
 
@@ -182,7 +193,7 @@ def test_prefill_kernel_tilings_match_oracle():
     ql = jnp.array([4, 2], jnp.int32)
     want = np.asarray(ref.paged_prefill_attention_ref(
         q, kp, vp, bt, qs, ql), np.float32)
-    for kv_block, head_block in ((8, 2), (32, 2), (16, 1)):
+    for kv_block, head_block in ((32, 2), (96, 2), (64, 1)):
         got = paged_prefill_attention(q, kp, vp, bt, qs, ql,
                                       interpret=True, kv_block=kv_block,
                                       head_block=head_block)
@@ -199,13 +210,15 @@ def test_prefill_kernel_tilings_match_oracle():
 # ======================================================================
 def test_sweep_selects_nondefault_for_page32(tmp_path):
     """Pinned-config regression for the reproducibility probe: on the
-    interpret path, one grid step per whole page=32 measurably beats
-    the 16-slot default tile, and the sweep must keep finding it (the
-    benchmark showed a ~4x margin; acceptance-criterion shape)."""
+    interpret path, fewer, larger blocks over a 16-page table of
+    page=32 measurably beat the rule's 128-token blocks (four a row;
+    1.2-1.6x here), and the sweep must keep finding a block larger than
+    the rule's. The gate at 1.0 leaves only the candidates the model
+    prices no worse than the rule's: the larger blocks."""
     autotune.enable(str(tmp_path / "cache.json"))
     entry = autotune.sweep("paged_attention", B=4, Hq=4, Hkv=2, D=16,
-                           page=32, pps=4, reps=5)
-    assert entry["kv_block"] == 32, entry
+                           page=32, pps=16, reps=5, gate_ratio=1.0)
+    assert entry["kv_block"] > kv_block_tokens(32, 32, 4, 16), entry
     assert entry["measured_us"] < entry["default_us"]
 
 
@@ -216,29 +229,30 @@ def test_sweep_roofline_gate_blocks_measured_winner(tmp_path):
     autotune.enable(str(tmp_path / "cache.json"))
     entry = autotune.sweep("paged_attention", B=4, Hq=4, Hkv=2, D=16,
                            page=32, pps=4, reps=1, gate_ratio=1e-9)
-    assert entry["kv_block"] == _default_kv_block(32)
+    assert entry["kv_block"] == kv_block_tokens(32, 32, 4, 4)
     assert entry["head_block"] == 2
 
 
 def test_modeled_cost_orders_step_and_launch_overheads():
     kw = dict(B=4, Hkv=2, D=16, page=32, pps=4)
-    # smaller tiles -> more grid steps -> strictly costlier model
-    assert autotune.modeled_cost_us(kv_block=8, head_block=2, **kw) \
-        > autotune.modeled_cost_us(kv_block=16, head_block=2, **kw) \
-        > autotune.modeled_cost_us(kv_block=32, head_block=2, **kw)
-    # head splitting doubles launch dispatches
-    assert autotune.modeled_cost_us(kv_block=32, head_block=1, **kw) \
-        > autotune.modeled_cost_us(kv_block=32, head_block=2, **kw)
+    # smaller blocks -> more blocks a row -> strictly costlier model
+    assert autotune.modeled_cost_us(kv_block=32, head_block=2, **kw) \
+        > autotune.modeled_cost_us(kv_block=64, head_block=2, **kw) \
+        > autotune.modeled_cost_us(kv_block=128, head_block=2, **kw)
+    # head splitting doubles launch dispatches and blocks
+    assert autotune.modeled_cost_us(kv_block=128, head_block=1, **kw) \
+        > autotune.modeled_cost_us(kv_block=128, head_block=2, **kw)
 
 
 def test_candidate_space_covers_default_and_whole_page():
-    cfgs = autotune.candidate_configs(32, 2)
+    cfgs = autotune.candidate_configs(32, 2, D=16, pps=6)
     kvs = {c["kv_block"] for c in cfgs}
-    assert {16, 32} <= kvs          # static default + whole page
-    assert all(32 % kb == 0 for kb in kvs)
+    # one page, the block rule's pick, and the whole (non-pow2) table
+    assert {32, kv_block_tokens(32, 32, 4, 6), 6 * 32} <= kvs
+    assert all(kb % 32 == 0 for kb in kvs)
     assert {c["head_block"] for c in cfgs} == {1, 2}
-    assert {c["head_block"] for c in autotune.candidate_configs(8, 1)} \
-        == {1}
+    assert {c["head_block"] for c in
+            autotune.candidate_configs(8, 1, D=16, pps=4)} == {1}
 
 
 def test_tuned_lookup_feeds_the_kernel(tmp_path):

@@ -70,21 +70,48 @@ def _case(key, B, Q, Hq, Hkv, D, page, pps, dtype=jnp.float32):
 # ======================================================================
 # kernel parity
 # ======================================================================
+def _fused_case(*dims, rows=None, kv_block=None):
+    """(B, Q, Hq, Hkv, D, page, pps), optional explicit (q_start,
+    q_lens) rows and block."""
+    return pytest.param(*dims, rows, kv_block,
+                        id="-".join(map(str, dims)))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "B,Q,Hq,Hkv,D,page,pps",
+    "B,Q,Hq,Hkv,D,page,pps,rows,kv_block",
     [
-        (3, 4, 4, 2, 16, 8, 4),      # GQA, mixed q_lens
-        (2, 8, 8, 2, 32, 8, 5),      # chunk spans pages
-        (1, 7, 4, 1, 16, 4, 6),      # MQA, odd Q
-        (4, 5, 6, 3, 16, 5, 4),      # non-pow2 page, padded row
-        (2, 1, 4, 2, 16, 8, 4),      # decode-only round (Q=1)
+        _fused_case(3, 4, 4, 2, 16, 8, 4),      # GQA, mixed q_lens
+        _fused_case(2, 8, 8, 2, 32, 8, 5),      # chunk spans pages
+        _fused_case(1, 7, 4, 1, 16, 4, 6),      # MQA, odd Q
+        _fused_case(4, 5, 6, 3, 16, 5, 4),      # non-pow2 page, padded row
+        _fused_case(2, 1, 4, 2, 16, 8, 4),      # decode-only round (Q=1)
+        # 2-page blocks over a 10-page table: rows end on a block edge
+        # (32, 32, 64), mid-block (41, 12), and a q_lens == 0 row
+        # between live ones; Q 32, Hkv 2 with G 4
+        _fused_case(6, 32, 8, 2, 16, 8, 10,
+                    rows=((0, 16, 40, 7, 3, 63), (32, 16, 1, 0, 9, 1)),
+                    kv_block=16),
+        # the block rule's 128 tokens over 20 pages (not a multiple):
+        # Q 1 with Hkv 8 and G 6; Q 32 with Hkv 1 and G 4
+        _fused_case(4, 1, 48, 8, 16, 8, 20,
+                    rows=((149, 127, 0, 5), (1, 1, 0, 1))),
+        _fused_case(3, 32, 4, 1, 32, 8, 20,
+                    rows=((100, 96, 0), (32, 32, 0))),
+        # 2-page blocks over 9 pages of 16: Hkv 2 with G 6, Q 32
+        _fused_case(4, 32, 12, 2, 16, 16, 9,
+                    rows=((0, 100, 60, 0), (32, 32, 4, 0)),
+                    kv_block=32),
     ])
-def test_fused_kernel_matches_ref(B, Q, Hq, Hkv, D, page, pps, dtype):
+def test_fused_kernel_matches_ref(B, Q, Hq, Hkv, D, page, pps, rows,
+                                  kv_block, dtype):
     tol = TOL[jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32]
     q, kp, vp, bt, qs, ql = _case(jax.random.PRNGKey(0), B, Q, Hq, Hkv,
                                   D, page, pps, dtype)
-    got = paged_prefill_attention(q, kp, vp, bt, qs, ql, interpret=True)
+    if rows is not None:
+        qs, ql = (jnp.array(r, jnp.int32) for r in rows)
+    got = paged_prefill_attention(q, kp, vp, bt, qs, ql, interpret=True,
+                                  kv_block=kv_block)
     want = ref.paged_prefill_attention_ref(q, kp, vp, bt, qs, ql)
     for b in range(B):       # padding tokens are unspecified: skip them
         n = int(ql[b])
@@ -133,6 +160,46 @@ def test_fused_kernel_stats_stripes_merge():
             np.testing.assert_allclose(
                 np.asarray(got)[b, :n], np.asarray(want, np.float32)[b, :n],
                 rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shift", ["q_start", "pos_offset"])
+def test_fused_kernel_stats_merge_over_blocks(shift):
+    """The stripe merge of ``test_fused_kernel_stats_stripes_merge`` on
+    multi-page blocks: pages of 16 slots striped in 2 or 4 shards of 8
+    or 4 slots (2-page blocks of 8-slot shards; a 4-slot shard is a
+    block alone), Q 32, a ``q_lens == 0`` row between live ones, rows
+    ending mid-block and on a block edge; each shard's slot offset goes
+    either into the traced ``q_start`` or into the static
+    ``pos_offset``."""
+    q, kp, vp, bt, _, _ = _case(jax.random.PRNGKey(3), 4, 32, 8, 2, 16,
+                                16, 6)
+    qs = jnp.array([0, 40, 5, 64], jnp.int32)
+    ql = jnp.array([32, 23, 0, 17], jnp.int32)
+    want = ref.paged_prefill_attention_ref(q, kp, vp, bt, qs, ql)
+    page = kp.shape[1]
+    for S in (2, 4):
+        psl = page // S
+        outs = []
+        for s in range(S):
+            kw = (dict(pos_offset=s * psl) if shift == "pos_offset"
+                  else {})
+            o, m, l = paged_prefill_attention(
+                q, kp[:, s * psl:(s + 1) * psl],
+                vp[:, s * psl:(s + 1) * psl], bt,
+                qs if kw else qs - s * psl, ql, pos_stride=page,
+                return_stats=True, interpret=True,
+                kv_block=2 * psl if psl % 8 == 0 else psl, **kw)
+            outs.append((o.astype(jnp.float32), m, l))
+        m_star = jnp.max(jnp.stack([m for _, m, _ in outs]), axis=0)
+        ws = [l * jnp.exp(m - m_star) for _, m, l in outs]
+        den = jnp.maximum(sum(ws), 1e-30)
+        got = sum(o * w[..., None] for (o, _, _), w in zip(outs, ws)) \
+            / den[..., None]
+        for b in range(q.shape[0]):
+            n = int(ql[b])
+            np.testing.assert_allclose(
+                np.asarray(got)[b, :n], np.asarray(want, np.float32)[b, :n],
+                rtol=2e-5, atol=2e-5, err_msg=f"S={S} row {b}")
 
 
 def test_q_bucket():

@@ -44,16 +44,33 @@ def test_flash_prefill_sweep(B, Hq, Hkv, Sq, Skv, D, bq, bkv, window,
         rtol=_tol(dtype), atol=_tol(dtype))
 
 
+def _paged_case(*dims, lens=None, kv_block=None):
+    """(B, Hq, Hkv, D, page, pps), optional explicit lengths and block."""
+    return pytest.param(*dims, lens, kv_block,
+                        id="-".join(map(str, dims)))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "B,Hq,Hkv,D,page,pps",
+    "B,Hq,Hkv,D,page,pps,lens,kv_block",
     [
-        (1, 2, 2, 16, 8, 2),
-        (3, 8, 2, 32, 8, 5),
-        (2, 4, 1, 64, 16, 4),
-        (4, 16, 8, 32, 4, 8),
+        _paged_case(1, 2, 2, 16, 8, 2),
+        _paged_case(3, 8, 2, 32, 8, 5),
+        _paged_case(2, 4, 1, 64, 16, 4),
+        _paged_case(4, 16, 8, 32, 4, 8),
+        # 2-page blocks over a 7-page table (a tail block): rows end
+        # mid-block, exactly on block edges, at one token, at the cap
+        _paged_case(5, 8, 2, 16, 8, 7, lens=(21, 32, 1, 56, 16),
+                    kv_block=16),
+        # the block rule's 128 tokens over 20 pages (not a multiple):
+        # Hkv 8 with G 4, Hkv 1 with G 6, Hkv 2 with G 6
+        _paged_case(3, 32, 8, 16, 8, 20, lens=(150, 128, 5)),
+        _paged_case(3, 6, 1, 32, 8, 20, lens=(129, 160, 64)),
+        _paged_case(3, 12, 2, 16, 16, 9, lens=(100, 144, 32),
+                    kv_block=32),
     ])
-def test_paged_attention_sweep(B, Hq, Hkv, D, page, pps, dtype):
+def test_paged_attention_sweep(B, Hq, Hkv, D, page, pps, lens, kv_block,
+                               dtype):
     num_pages = B * pps + 3
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
@@ -62,8 +79,10 @@ def test_paged_attention_sweep(B, Hq, Hkv, D, page, pps, dtype):
     bt = jax.random.permutation(
         ks[3], num_pages)[:B * pps].reshape(B, pps).astype(jnp.int32)
     # ragged lengths incl. partially-filled last page and a 1-token seq
-    sl = jnp.array([(i * 7) % (page * pps) + 1 for i in range(B)], jnp.int32)
-    out = paged_attention(q, kp, vp, bt, sl, interpret=True)
+    sl = jnp.array(lens or [(i * 7) % (page * pps) + 1 for i in range(B)],
+                   jnp.int32)
+    out = paged_attention(q, kp, vp, bt, sl, interpret=True,
+                          kv_block=kv_block)
     want = ref.paged_attention_ref(q, kp, vp, bt, sl)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),

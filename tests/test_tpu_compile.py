@@ -3,9 +3,11 @@
 Interpret mode runs a Pallas kernel in Python and checks none of what
 Mosaic checks (tile alignment, VMEM, layouts), so a kernel can pass
 every CPU test and be refused by the chip's compiler. These tests
-compile the paged attention kernels at qwen2-1.5b's attention widths in
-bf16, and the fused serving step at its published widths with two
-layers, for a described ``v5e:2x2`` device — no chip time needed.
+compile the paged attention kernels at qwen2-1.5b's and qwen3-4b's
+attention widths in bf16, at the benchmark's served shapes among
+others, and the fused serving step at qwen2-1.5b's published widths
+with two layers, for a described ``v5e:2x2`` device — no chip time
+needed.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and every
@@ -60,24 +62,45 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("stats", [False, True], ids=["plain", "stats"])
-@pytest.mark.parametrize("Q", [1, 16])
-@pytest.mark.parametrize("page", [16, 32])
-def test_paged_kernels_compile_for_v5e(one_chip, qwen, page, Q, stats):
-    """Q=1 is the decode entry point, Q=16 a fused prefill chunk;
-    ``stats`` is the variant the slot-sharded plane merges."""
-    hd, Hkv = qwen.resolved_head_dim, qwen.num_kv_heads
+def _kernel_cases():
+    """(model, slots, pages_per_seq, page, Q, stats): gateway-sized
+    qwen2-1.5b rows at pages of 16 and 32, then both benchmark
+    configurations at their served shapes (slots 32 and 16, 2,048-token
+    context in 16-token pages, decode and a 32-token chunk)."""
+    for page in (16, 32):
+        for Q in (1, 16):
+            for stats in (False, True):
+                yield pytest.param(
+                    "qwen2-1.5b", B, PPS, page, Q, stats,
+                    id=f"{page}-{Q}-{'stats' if stats else 'plain'}")
+    for model, slots in (("qwen2-1.5b", 32), ("qwen3-4b", 16)):
+        for Q in (1, 32):
+            for stats in (False, True):
+                yield pytest.param(
+                    model, slots, 128, 16, Q, stats,
+                    id=f"{model}-B{slots}-pps128-Q{Q}-"
+                       f"{'stats' if stats else 'plain'}")
+
+
+@pytest.mark.parametrize("model,slots,pps,page,Q,stats", _kernel_cases())
+def test_paged_kernels_compile_for_v5e(one_chip, model, slots, pps, page,
+                                       Q, stats):
+    """Q=1 is the decode entry point, Q>1 a fused prefill chunk;
+    ``stats`` is the variant the slot-sharded plane merges. qwen3-4b's
+    8 KV heads make a block of 8 pages 256 KB of K and as much of V."""
+    cfg = get_config(model)
+    hd, Hkv = cfg.resolved_head_dim, cfg.num_kv_heads
     kv = _sds((PAGES + 1, page, Hkv * hd), jnp.bfloat16, one_chip)
-    bt = _sds((B, PPS), jnp.int32, one_chip)
-    rows = _sds((B,), jnp.int32, one_chip)
+    bt = _sds((slots, pps), jnp.int32, one_chip)
+    rows = _sds((slots,), jnp.int32, one_chip)
     if Q == 1:
         fn = functools.partial(paged_attention, return_stats=stats)
-        args = (_sds((B, qwen.num_heads, hd), jnp.bfloat16, one_chip),
+        args = (_sds((slots, cfg.num_heads, hd), jnp.bfloat16, one_chip),
                 kv, kv, bt, rows)
     else:
         fn = functools.partial(paged_prefill_attention, return_stats=stats)
-        args = (_sds((B, Q, qwen.num_heads, hd), jnp.bfloat16, one_chip),
-                kv, kv, bt, rows, rows)
+        args = (_sds((slots, Q, cfg.num_heads, hd), jnp.bfloat16,
+                     one_chip), kv, kv, bt, rows, rows)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

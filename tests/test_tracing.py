@@ -123,6 +123,25 @@ def test_round_opens_the_documented_spans(tiny, recorder, fused):
         x["B"] * x["Q"] for x in launches)
 
 
+def test_attention_block_counters_match_a_hand_count(tiny):
+    """``attn_blocks_live``/``attn_blocks_grid`` count the kernel's KV
+    blocks: 8-token pages stack into the rule's 128-token blocks, so a
+    320-token context is 3 blocks a row and the grid is slots x 3."""
+    cfg, params = tiny
+    rng = np.random.default_rng(0)
+    eng = PagedRealtimeEngine(cfg, params, slots=2, page_size=8,
+                              pages_per_seq=40, num_pages=64)
+    assert eng._attn_block == 128
+    a = eng.submit_turn("a", rng.integers(0, cfg.vocab_size, size=140),
+                        max_new_tokens=3)
+    b = eng.submit_turn("b", rng.integers(0, cfg.vocab_size, size=10),
+                        max_new_tokens=3)
+    eng.run_round({a: 140, b: 10})    # 140 tokens: 2 blocks; 10: 1
+    assert (eng.attn_blocks_live, eng.attn_blocks_grid) == (3, 6)
+    eng.run_round({a: 1, b: 1})       # 141 tokens: 2 blocks; 11: 1
+    assert (eng.attn_blocks_live, eng.attn_blocks_grid) == (6, 12)
+
+
 def _reload_on_path(eng, rng, cfg):
     """Session "a" finishes a turn, loses two pages to DRAM, and comes
     back: its next admission must reload them on the turn's path."""
