@@ -137,8 +137,8 @@ def compare(run, sample: Sample, engine_ids: Dict[str, list], params, *,
     batch = reference.batch_for(run.conf, pad)
     gap = float("inf")
     if n:
-        gaps = reference.served_gaps(run.conf, params, seqs, poss, toks,
-                                     pad_to=pad, batch=batch)
+        gaps = reference.served_gaps(run.cell.arch, run.conf, params, seqs,
+                                     poss, toks, pad_to=pad, batch=batch)
         gap = float(max(g.max() for g in gaps if len(g)))
         allg = np.concatenate(gaps)
         _log(f"check gaps: n={len(allg)} nonzero={int((allg > 0).sum())} "
@@ -149,9 +149,9 @@ def compare(run, sample: Sample, engine_ids: Dict[str, list], params, *,
             # judged instead of the served one
             _log(f"check program gap_max (control run, not judged): "
                  f"{gap!r}")
-            cg = reference.served_gaps(run.conf, params, seqs, poss, toks,
-                                       pad_to=pad, batch=batch,
-                                       control=True)
+            cg = reference.served_gaps(run.cell.arch, run.conf, params,
+                                       seqs, poss, toks, pad_to=pad,
+                                       batch=batch, control=True)
             gap = float(max(g.max() for g in cg if len(g)))
             allc = np.concatenate(cg)
             _log(f"control gaps: nonzero={int((allc > 0).sum())} "

@@ -1,9 +1,10 @@
 """The paged attention kernel's share of its roofline, in percent: the
 least time the chip needs for the FLOPs and bytes the valid query
-tokens require (``flops.attention_cost``; memory bound at these sizes)
-over the kernel's device time in the traced slice. Grid steps past a
-row's length count as time, not as work, so they lower it."""
-from bench.flops import attention_cost, least_time
+tokens require (``attention_cost`` of ``bench/arch/common.py``; memory
+bound at these sizes) over the kernel's device time in the traced
+slice. Grid steps past a row's length count as time, not as work, so
+they lower it."""
+from bench.arch.common import attention_cost, least_time
 
 KERNEL = "paged_attention"
 

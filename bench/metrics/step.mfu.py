@@ -1,8 +1,7 @@
-"""Model FLOPs of the valid tokens launched in the traced slice over the
-device time of the fused step's executions there (the programs that
-run inside the ``bench.launch`` spans) times the chip's peak bf16
-FLOP/s, in percent."""
-from bench.flops import step_flops
+"""Model FLOPs of the valid tokens launched in the traced slice (the
+configuration's architecture file, ``step_flops``) over the device time
+of the fused step's executions there (the programs that run inside the
+``bench.launch`` spans) times the chip's peak bf16 FLOP/s, in percent."""
 
 
 def read(run):
@@ -13,5 +12,5 @@ def read(run):
     ls = run.traced_launches()
     if dev_s <= 0 or not ls:
         return None
-    flops = sum(step_flops(run.conf, x.rows) for x in ls)
+    flops = sum(run.cell.arch.step_flops(run.conf, x.rows) for x in ls)
     return 100.0 * flops / (dev_s * run.peak["bf16_flops_per_s"])

@@ -163,23 +163,6 @@ def compile_cache() -> str:
     return path
 
 
-def model_config(conf: dict):
-    """The program's config object for a configuration file."""
-    from repro.configs.registry import ModelConfig
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"], d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"], mlp_kind="swiglu",
-        qk_norm=conf["qk_norm"], qkv_bias=conf["attention_bias"],
-        rope_theta=conf["rope_theta"], rms_eps=conf["rms_norm_eps"],
-        tie_embeddings=conf["tie_word_embeddings"],
-        param_dtype=conf["torch_dtype"], dtype=conf["torch_dtype"],
-        source=conf["source"])
-
-
 def build_gateway(conf: dict, cfg, params):
     from repro.serving.gateway.harness import build_gateway as build
     e = conf["engine"]
@@ -473,8 +456,9 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     from bench import weights
     t0 = time.perf_counter()
-    cfg = model_config(run.conf)
-    params = weights.make(run.conf, seed, dev)
+    cfg = cell.arch.program_config(run.conf)
+    params = weights.make(cell.arch.shapes(run.conf),
+                          run.conf["torch_dtype"], seed, dev)
     jax.block_until_ready(params)
     from repro.models import init_params
     if not weights.same_layout(params, lambda: init_params(

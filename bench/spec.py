@@ -4,15 +4,31 @@
 traffic mix and a chip count; the files that hold them are found by
 name, never by code:
 
-- ``bench/configs/<config>.json``: the model's sizes, as published, and
-  the engine settings it is served with;
+- ``bench/configs/<config>.json``: the model's sizes, as published, its
+  ``architecture`` (the Hugging Face class name) and the engine
+  settings it is served with;
+- ``bench/arch/<architecture>.py``: what is particular to that
+  architecture (``bench/arch/common.py`` holds the pieces that such
+  files share):
+
+  - ``program_config(conf)``: the serving program's ``ModelConfig``;
+  - ``shapes(conf)``: leaf path -> (shape, std) of the weight tree in
+    the program's layout, which ``weights.make`` draws from the seed;
+  - ``hidden(conf, w, tokens, *, lower=False)``: the final hidden
+    states [B, S, d] in float32 of token rows [B, S], and
+    ``logits(conf, w, x, *, lower=False)``: the output head over
+    hidden rows [..., d]. Together the plain reference, which imports
+    nothing of the program; ``lower=True`` is the float8 control;
+  - ``step_flops(conf, rows)``: model FLOPs of one fused step whose
+    valid rows are ``(q_start, q_len)``, read by ``step.mfu``;
+
 - ``bench/traffic/<traffic>.json``: the parameters of the one general
   session generator (``loadgen/generator.py``);
 - ``bench/metrics/<metric>.py``: one reader per metric, with a
   ``read(run)`` function that returns a number or None.
 
-A later change adds a model, a mix or a metric by adding files and
-entries; nothing here names one.
+A later change adds a model, an architecture, a mix or a metric by
+adding files and entries; nothing here names one.
 """
 from __future__ import annotations
 
@@ -20,10 +36,13 @@ import importlib.util
 import json
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
 REPO_ROOT = BENCH_DIR.parent
+ARCH_FUNCTIONS = ("program_config", "shapes", "hidden", "logits",
+                  "step_flops")
 
 
 class SpecError(Exception):
@@ -56,6 +75,7 @@ class Cell:
     traffic: dict
     end_to_end: List[Metric] = field(default_factory=list)
     per_layer: List[Metric] = field(default_factory=list)
+    arch: Optional[ModuleType] = None
 
 
 def _load_json(path: Path) -> dict:
@@ -102,7 +122,33 @@ def resolve_cell(name: str, root: Path = REPO_ROOT) -> Cell:
     return Cell(name=name, config_name=w["config"],
                 traffic_name=w["traffic"], chips=int(w["chips"]),
                 config=conf, traffic=traffic, end_to_end=e2e,
-                per_layer=per)
+                per_layer=per, arch=architecture(conf, root))
+
+
+def _load(path: Path, prefix: str, name: str) -> ModuleType:
+    modname = prefix + "".join(c if c.isalnum() else "_" for c in name)
+    mspec = importlib.util.spec_from_file_location(modname, path)
+    mod = importlib.util.module_from_spec(mspec)
+    mspec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(conf: dict, root: Path = REPO_ROOT) -> ModuleType:
+    """The module ``root/bench/arch/<architecture>.py`` named by the
+    configuration's ``architecture`` key."""
+    name = conf.get("architecture")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise SpecError(f"configuration {conf.get('name')!r} names no "
+                        f"architecture (got {name!r})")
+    path = Path(root) / "bench" / "arch" / f"{name}.py"
+    if not path.exists():
+        raise SpecError(f"no file for architecture {name!r} at {path}")
+    mod = _load(path, "bench_arch_", name)
+    missing = [f for f in ARCH_FUNCTIONS
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SpecError(f"{path} defines no {', '.join(missing)}")
+    return mod
 
 
 def metric_reader(name: str, root: Path = REPO_ROOT) -> Callable:
@@ -110,11 +156,7 @@ def metric_reader(name: str, root: Path = REPO_ROOT) -> Callable:
     path = Path(root) / "bench" / "metrics" / f"{name}.py"
     if not path.exists():
         raise SpecError(f"no reader for metric {name!r} at {path}")
-    modname = "bench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in name)
-    mspec = importlib.util.spec_from_file_location(modname, path)
-    mod = importlib.util.module_from_spec(mspec)
-    mspec.loader.exec_module(mod)
+    mod = _load(path, "bench_metric_", name)
     if not hasattr(mod, "read"):
         raise SpecError(f"{path} defines no read(run)")
     return mod.read

@@ -28,22 +28,21 @@ def test_the_float8_control_fails_the_check(cell):
     import jax
     c = spec.resolve_cell(cell)
     conf = small(c.config)
-    w = weights.make(conf, 7, jax.devices()[0])
+    w = weights.make(c.arch.shapes(conf), conf["torch_dtype"], 7,
+                     jax.devices()[0])
     rng = np.random.default_rng(7)
     S, n = 256, 4
     seqs = [rng.integers(0, conf["vocab_size"], S).astype(np.int32)
             for _ in range(n)]
     pos = [np.arange(S) for _ in range(n)]
-    x = reference.hidden(conf, w, np.stack(seqs))
-    _, _, head = reference._programs(reference._key(conf), False)
-    best = [np.asarray(jax.numpy.argmax(head(x[i], w["final_norm"],
-                                             w["embed"]), -1))
+    x = c.arch.hidden(conf, w, np.stack(seqs))
+    best = [np.asarray(jax.numpy.argmax(c.arch.logits(conf, w, x[i]), -1))
             for i in range(n)]
-    sound = reference.served_gaps(conf, w, seqs, pos, best, pad_to=S,
-                                  batch=2)
+    sound = reference.served_gaps(c.arch, conf, w, seqs, pos, best,
+                                  pad_to=S, batch=2)
     assert max(g.max() for g in sound) == 0.0
-    ctl = reference.served_gaps(conf, w, seqs, pos, best, pad_to=S,
-                                batch=2, control=True)
+    ctl = reference.served_gaps(c.arch, conf, w, seqs, pos, best,
+                                pad_to=S, batch=2, control=True)
     widest = max(g.max() for g in ctl)
     assert widest > c.traffic["check"]["gap_limit"], widest
     # most positions still agree: the control is close, not broken

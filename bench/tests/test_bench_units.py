@@ -8,7 +8,8 @@ from pathlib import Path
 
 import pytest
 
-from bench import flops, peaks, spec, stats
+from bench import peaks, spec, stats
+from bench.arch import common
 from bench.loadgen.client import TurnLog, Window
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -57,20 +58,20 @@ def test_flops_and_bytes_against_hand_counts():
             "num_attention_heads": 4, "num_key_value_heads": 2,
             "head_dim": 2, "intermediate_size": 16, "vocab_size": 10}
     # q 8*8, k 8*4, v 8*4, o 8*8, mlp 3*8*16
-    assert flops.layer_weight_params(conf) == 64 + 32 + 32 + 64 + 384
+    assert common.dense_layer_params(conf) == 64 + 32 + 32 + 64 + 384
     rows = [(3, 1), (0, 2)]      # a decode row at position 3; a prefill
     # attended: decode 4; prefill tokens see 1 and 2
-    assert flops.attended(rows) == 4 + 1 + 2
-    f = flops.step_flops(conf, rows)
+    assert common.attended(rows) == 4 + 1 + 2
+    f = common.dense_step_flops(conf, rows)
     assert f == 2 * 2 * 576 * 3 + 4 * 2 * 4 * 2 * 7 + 2 * 8 * 10 * 2
-    af, ab = flops.attention_cost(conf, rows)
+    af, ab = common.attention_cost(conf, rows)
     assert af == 4 * 2 * 4 * 2 * 7
     # K,V up to each row's last position (4 + 2), 2 kv heads of 2;
     # q in and out out for 3 tokens, 4 heads of 2; bf16; 2 layers
     assert ab == 2 * ((4 + 2) * 2 * 2 * 2 + 3 * 2 * 4 * 2) * 2
     peak = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
-    assert flops.least_time(50.0, 1.0, peak) == (0.5, "compute")
-    assert flops.least_time(1.0, 50.0, peak) == (5.0, "memory")
+    assert common.least_time(50.0, 1.0, peak) == (0.5, "compute")
+    assert common.least_time(1.0, 50.0, peak) == (5.0, "memory")
 
 
 def test_unknown_device_kind_raises():
@@ -95,12 +96,24 @@ def test_every_cell_resolves_to_its_files():
             assert m.moves in {e.name for e in cell.end_to_end}
 
 
+NEW_ARCH = """
+def program_config(conf): return conf["name"]
+def shapes(conf): return {}
+def hidden(conf, w, tokens, *, lower=False): return None
+def logits(conf, w, x, *, lower=False): return None
+def step_flops(conf, rows): return 7.0
+"""
+
+
 def test_a_cell_defined_only_in_a_temp_dir_resolves(tmp_path):
     (tmp_path / "bench" / "configs").mkdir(parents=True)
     (tmp_path / "bench" / "traffic").mkdir()
     (tmp_path / "bench" / "metrics").mkdir()
+    (tmp_path / "bench" / "arch").mkdir()
+    (tmp_path / "bench/arch/NewForCausalLM.py").write_text(NEW_ARCH)
     conf = json.loads((ROOT / "bench/configs/qwen2-1.5b.json").read_text())
     conf["name"] = "newmodel"
+    conf["architecture"] = "NewForCausalLM"
     (tmp_path / "bench/configs/newmodel.json").write_text(json.dumps(conf))
     mix = json.loads((ROOT / "bench/traffic/voice.json").read_text())
     mix["users"] = 3
@@ -119,10 +132,41 @@ def test_a_cell_defined_only_in_a_temp_dir_resolves(tmp_path):
     cell = spec.resolve_cell("newmodel.newmix", tmp_path)
     assert cell.traffic["users"] == 3
     assert cell.config["name"] == "newmodel"
+    assert cell.arch.program_config(cell.config) == "newmodel"
+    assert cell.arch.step_flops(cell.config, [(0, 1)]) == 7.0
     read = spec.readers(cell.per_layer, tmp_path)["new.metric"]
     assert read(None) == 42.0
     with pytest.raises(spec.SpecError):
         spec.resolve_cell("nope", tmp_path)
+
+
+@pytest.mark.parametrize(
+    "config", [c["name"] for c in spec.load_benchmark()["configs"]])
+def test_each_config_resolves_to_its_architecture_file(config):
+    bench = spec.load_benchmark()
+    entry = next(c for c in bench["configs"] if c["name"] == config)
+    conf = json.loads((ROOT / entry["file"]).read_text())
+    arch = spec.architecture(conf)
+    assert Path(arch.__file__) == \
+        ROOT / "bench" / "arch" / f"{conf['architecture']}.py"
+    for w in bench["workloads"]:
+        if w["config"] == config:
+            cell = spec.resolve_cell(w["name"])
+            assert Path(cell.arch.__file__) == Path(arch.__file__)
+
+
+def test_an_unknown_architecture_raises_naming_its_file(tmp_path):
+    conf = json.loads((ROOT / "bench/configs/qwen3-4b.json").read_text())
+    conf["architecture"] = "NoSuchForCausalLM"
+    with pytest.raises(spec.SpecError) as e:
+        spec.architecture(conf, tmp_path)
+    assert str(tmp_path / "bench" / "arch" / "NoSuchForCausalLM.py") \
+        in str(e.value)
+    (tmp_path / "bench" / "arch").mkdir(parents=True)
+    (tmp_path / "bench/arch/NoSuchForCausalLM.py").write_text(
+        "def shapes(conf):\n    return {}\n")
+    with pytest.raises(spec.SpecError, match="program_config"):
+        spec.architecture(conf, tmp_path)
 
 
 def test_run_exits_nonzero_off_the_chip():
