@@ -1,6 +1,6 @@
 """qwen3-4b — dense GQA with qk_norm.
 
-[hf:Qwen/Qwen3-8B family; hf] 36L d_model=2560 32H (GQA kv=8) d_ff=9728
+[hf:Qwen/Qwen3-4B; hf] 36L d_model=2560 32H (GQA kv=8) d_ff=9728
 vocab=151936. Qwen3 uses an explicit head_dim=128 (> d_model/num_heads).
 """
 from repro.configs.registry import ModelConfig, register
@@ -19,5 +19,5 @@ CONFIG = register(ModelConfig(
     qk_norm=True,
     rope_theta=1_000_000.0,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-4B",
 ))

@@ -23,6 +23,16 @@ class MoEConfig:
     router_jitter: float = 0.0
     first_dense_layers: int = 0     # leading dense layers (deepseek-v2 style)
     d_ff_dense: int = 0             # FFN width of those dense layers
+    # the experts this chip holds in an expert-parallel deployment:
+    # ``held_count`` experts from ``held_offset`` (0 holds all
+    # ``num_experts``, which stays the router's published width)
+    held_offset: int = 0
+    held_count: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.held_count or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -225,7 +235,7 @@ def _ensure_loaded() -> None:
     from repro.configs import (  # noqa: F401
         whisper_tiny, h2o_danube_1_8b, qwen3_4b, nemotron_4_340b,
         qwen2_1_5b, recurrentgemma_9b, mamba2_1_3b, deepseek_v2_236b,
-        phi3_5_moe, paligemma_3b,
+        phi3_5_moe, paligemma_3b, qwen3_30b_a3b,
     )
 
 

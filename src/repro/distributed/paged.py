@@ -62,6 +62,11 @@ class PagedKVLayout:
 
     def __init__(self, cfg, mesh, page_size: int):
         assert "model" in mesh.axis_names, mesh.axis_names
+        if getattr(cfg, "moe", None) is not None:
+            raise NotImplementedError(
+                f"{cfg.name}: the sharded page planes serve no MoE "
+                "config; its held-expert layer runs on one device (no "
+                "cell shards it yet)")
         self.cfg = cfg
         self.mesh = auto_axes(mesh)
         self.page_size = page_size

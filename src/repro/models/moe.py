@@ -1,6 +1,6 @@
 """Mixture-of-Experts layer.
 
-Two execution paths with identical numerics:
+Training runs one of two capacity paths with identical numerics:
 
 - ``moe_local``: single-device / pjit-friendly. Dispatch is gather-based
   (argsort routing -> [E, C] token-index table), so HLO FLOPs are
@@ -12,6 +12,11 @@ Two execution paths with identical numerics:
 
 Capacity-factor dropping matches the published dropping implementations
 (tokens beyond an expert's capacity fall back to the residual path).
+
+Serving runs ``moe_held``, the dropless held-expert layer (DESIGN.md
+§17): no capacity, every valid token routed over all of the router's
+experts, and only the experts this chip holds (``MoEConfig.held_*``)
+computed, in the ``moe_experts`` kernel.
 """
 from __future__ import annotations
 
@@ -23,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import moe_experts as kernel
 from repro.models.layers import dense_init, mlp_apply, mlp_init
 
 # Expert tensor-parallelism over the 'data' axis (decode_2d layouts):
@@ -43,16 +49,19 @@ def moe_init(key, cfg, dtype):
     d = cfg.d_model
     f = m.d_ff_expert
     ks = jax.random.split(key, 5)
-    ek = jax.random.split(ks[0], m.num_experts)
+    ek = jax.random.split(ks[0], m.held)
     p = {
-        "router": dense_init(ks[1], (d, m.num_experts), d, jnp.float32),
+        # training keeps the router in f32; a chip's served share holds
+        # it in the served dtype (``moe_held`` accumulates in f32)
+        "router": dense_init(ks[1], (d, m.num_experts), d,
+                             dtype if m.held_count else jnp.float32),
         "w_gate": jax.vmap(lambda k: dense_init(k, (d, f), d, dtype))(ek),
         "w_up": jax.vmap(
             lambda k: dense_init(k, (d, f), d, dtype))(
-                jax.random.split(ks[2], m.num_experts)),
+                jax.random.split(ks[2], m.held)),
         "w_down": jax.vmap(
             lambda k: dense_init(k, (f, d), f, dtype))(
-                jax.random.split(ks[3], m.num_experts)),
+                jax.random.split(ks[3], m.held)),
     }
     if m.num_shared_experts:
         p["shared"] = mlp_init(ks[4], d, f * m.num_shared_experts,
@@ -212,7 +221,40 @@ def moe_ep(params, x, cfg, mesh, *, data_axes=("data",), model_axis="model"):
 
 def moe_apply(params, x, cfg, mesh=None, *, data_axes=("data",),
               model_axis="model"):
+    if cfg.moe.held != cfg.moe.num_experts:
+        raise ValueError(
+            f"{cfg.name} holds {cfg.moe.held} of {cfg.moe.num_experts} "
+            "experts: a chip's share is served by moe_held, not trained")
     if mesh is None:
         return moe_local(params, x, cfg)
     return moe_ep(params, x, cfg, mesh, data_axes=data_axes,
                   model_axis=model_axis)
+
+
+# ----------------------------------------------------------------- served
+def moe_held(params, x, cfg, valid, *, interpret: bool = False):
+    """The dropless held-expert layer (HF ``Qwen3MoeSparseMoeBlock`` on
+    this chip's experts): x [B, S, d] (normed), valid [B, S] bool ->
+    (y [B, S, d], counts [3] i32).
+
+    Every valid token is routed over all ``num_experts`` (softmax in
+    f32, top-k, renormalised to sum to 1); y is the sum of
+    its routing weight times expert FFN over the chosen experts that
+    this chip holds. Invalid tokens route nothing and get y = 0. No
+    capacity: nothing is dropped, and a token's y depends only on
+    itself. ``counts`` are the routed pairs, those on held experts, and
+    the held experts touched."""
+    m = cfg.moe
+    B, S, d = x.shape
+    x2 = x.reshape(B * S, d)
+    logits = jnp.dot(x2, params["router"], preferred_element_type=jnp.float32)
+    weights, sel = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    p = kernel.plan(sel, weights, valid.reshape(-1), offset=m.held_offset,
+                    held=m.held)
+    y = kernel.moe_experts(x2, p, params["w_gate"], params["w_up"],
+                           params["w_down"], interpret=interpret)
+    y = y.reshape(B, S, d).astype(x.dtype)
+    if m.num_shared_experts:
+        y = y + mlp_apply(params["shared"], x, "swiglu")
+    return y, p.counts

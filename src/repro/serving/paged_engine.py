@@ -60,6 +60,7 @@ from repro.kvcache.quant import KVWireCodec
 from repro.models import init_cache, prefill
 from repro.models import layers as L
 from repro.models.model import _embed, _logits, _mlp_block
+from repro.models.moe import moe_held
 from repro.serving.block_tables import BatchTables, FusedBatchTables, \
     LayerStackedPages, assemble, assemble_fused
 from repro.serving.engine import RoundLimitExceeded, _StepClock, \
@@ -75,6 +76,40 @@ def _merge_heads(x):
     return x.reshape(*x.shape[:-2], -1)
 
 
+def _held_ffn(cfg, lp, h, valid, interpret):
+    """The feed-forward half of a served MoE config's layer: the
+    dropless held-expert layer (DESIGN.md §17) on an expert layer, with
+    its routing counts; a leading dense layer counts nothing."""
+    if "moe" not in lp:
+        h, _ = _mlp_block(cfg, lp, h, None)
+        return h, jnp.zeros((3,), jnp.int32)
+    y, counts = moe_held(lp["moe"], L.rms_norm(h, lp["ln2"], cfg.rms_eps),
+                         cfg, valid, interpret=interpret)
+    return h + y, counts
+
+
+def _layers(body, params, x, k_pages, v_pages, moe: bool):
+    """Run ``body`` over the leading unstacked layers, then scan it over
+    the stacked ones. Returns (x, k_pages, v_pages, the routing counts
+    summed over layers, or None)."""
+    counts = jnp.zeros((3,), jnp.int32) if moe else None
+    npre = len(params.get("layers_pre", []))
+    for i, lp in enumerate(params.get("layers_pre", [])):
+        x, out = body(x, (lp, k_pages[i], v_pages[i]))
+        k_pages = k_pages.at[i].set(out[0])
+        v_pages = v_pages.at[i].set(out[1])
+        if moe:
+            counts = counts + out[2]
+    x, out = jax.lax.scan(
+        body, x, (params["layers"], k_pages[npre:], v_pages[npre:]))
+    kcs, vcs = out[:2]
+    k_pages = jnp.concatenate([k_pages[:npre], kcs]) if npre else kcs
+    v_pages = jnp.concatenate([v_pages[:npre], vcs]) if npre else vcs
+    if moe:
+        counts = counts + jnp.sum(out[2], axis=0)
+    return x, k_pages, v_pages, counts
+
+
 def paged_decode_step(cfg, params, tokens, positions, k_pages, v_pages,
                       block_tables, seq_lens, write_page, write_slot,
                       *, interpret: bool = False, plane=None):
@@ -83,7 +118,9 @@ def paged_decode_step(cfg, params, tokens, positions, k_pages, v_pages,
     tokens/positions/write_page/write_slot [B] i32;
     k_pages/v_pages [L, P+1, page, Hkv*hd]; block_tables [B, pps] i32;
     seq_lens [B] i32 (post-write attention lengths).
-    Returns (logits [B, V], k_pages, v_pages).
+    Returns (logits [B, V], k_pages, v_pages), and for an MoE config
+    the held-expert layers' routing counts [3] last (``_held_ffn``);
+    rows writing to the scratch page are padding and route nothing.
 
     ``plane`` swaps the page write + attention strategy: None is the
     single-device path; a ``distributed.paged.PagedKVLayout`` makes this
@@ -93,6 +130,9 @@ def paged_decode_step(cfg, params, tokens, positions, k_pages, v_pages,
     """
     x = _embed(cfg, params, tokens[:, None])
     pos = positions[:, None]                            # [B, 1]
+    moe = cfg.moe is not None
+    if moe:
+        valid = (write_page < k_pages.shape[1] - 1)[:, None]
 
     def body(carry, xs):
         lp, kc, vc = xs
@@ -110,19 +150,16 @@ def paged_decode_step(cfg, params, tokens, positions, k_pages, v_pages,
             a = plane.attend(q[:, 0], kc, vc, block_tables, seq_lens,
                              interpret=interpret)
         h = carry + L.attn_output(lp["attn"], a[:, None])
-        h, _ = _mlp_block(cfg, lp, h, None)
-        return h, (kc, vc)
+        if not moe:
+            h, _ = _mlp_block(cfg, lp, h, None)
+            return h, (kc, vc)
+        h, n = _held_ffn(cfg, lp, h, valid, interpret)
+        return h, (kc, vc, n)
 
-    npre = len(params.get("layers_pre", []))
-    for i, lp in enumerate(params.get("layers_pre", [])):
-        x, (kc, vc) = body(x, (lp, k_pages[i], v_pages[i]))
-        k_pages = k_pages.at[i].set(kc)
-        v_pages = v_pages.at[i].set(vc)
-    x, (kcs, vcs) = jax.lax.scan(
-        body, x, (params["layers"], k_pages[npre:], v_pages[npre:]))
-    k_pages = jnp.concatenate([k_pages[:npre], kcs]) if npre else kcs
-    v_pages = jnp.concatenate([v_pages[:npre], vcs]) if npre else vcs
-    return _logits(cfg, params, x)[:, 0], k_pages, v_pages
+    x, k_pages, v_pages, counts = _layers(body, params, x, k_pages,
+                                          v_pages, moe)
+    tail = (counts,) if moe else ()
+    return (_logits(cfg, params, x)[:, 0], k_pages, v_pages) + tail
 
 
 def paged_fused_step(cfg, params, tokens, positions, k_pages, v_pages,
@@ -151,8 +188,15 @@ def paged_fused_step(cfg, params, tokens, positions, k_pages, v_pages,
     ``paged_decode_step`` (None = single device; a ``PagedKVLayout``
     makes this the per-shard body of a shard_map — same code path, so
     sharded and unsharded engines cannot drift).
+
+    For an MoE config the held-expert layers' routing counts [3] come
+    last in the result (``_held_ffn``), beside the logits, and the
+    tokens past each row's ``q_lens`` route nothing.
     """
     x = _embed(cfg, params, tokens)                     # [B, Q, d]
+    moe = cfg.moe is not None
+    if moe:
+        valid = jnp.arange(tokens.shape[1])[None, :] < q_lens[:, None]
 
     def body(carry, xs):
         lp, kc, vc = xs
@@ -171,18 +215,15 @@ def paged_fused_step(cfg, params, tokens, positions, k_pages, v_pages,
             a = plane.attend_chunk(q, kc, vc, block_tables, q_start,
                                    q_lens, interpret=interpret)
         h = carry + L.attn_output(lp["attn"], a)
-        h, _ = _mlp_block(cfg, lp, h, None)
-        return h, (kc, vc)
+        if not moe:
+            h, _ = _mlp_block(cfg, lp, h, None)
+            return h, (kc, vc)
+        h, n = _held_ffn(cfg, lp, h, valid, interpret)
+        return h, (kc, vc, n)
 
-    npre = len(params.get("layers_pre", []))
-    for i, lp in enumerate(params.get("layers_pre", [])):
-        x, (kc, vc) = body(x, (lp, k_pages[i], v_pages[i]))
-        k_pages = k_pages.at[i].set(kc)
-        v_pages = v_pages.at[i].set(vc)
-    x, (kcs, vcs) = jax.lax.scan(
-        body, x, (params["layers"], k_pages[npre:], v_pages[npre:]))
-    k_pages = jnp.concatenate([k_pages[:npre], kcs]) if npre else kcs
-    v_pages = jnp.concatenate([v_pages[:npre], vcs]) if npre else vcs
+    x, k_pages, v_pages, counts = _layers(body, params, x, k_pages,
+                                          v_pages, moe)
+    tail = (counts,) if moe else ()
     last = jnp.maximum(q_lens - 1, 0)
     if spec:
         # the verify step consumes every position's argmax, so the full
@@ -193,12 +234,12 @@ def paged_fused_step(cfg, params, tokens, positions, k_pages, v_pages,
         outs = jnp.argmax(full, axis=-1).astype(jnp.int32)
         logits = jnp.take_along_axis(
             full, last[:, None, None], axis=1)[:, 0]
-        return logits, outs, k_pages, v_pages
+        return (logits, outs, k_pages, v_pages) + tail
     # only each row's last valid token's logits are consumed (the next
     # decode token / first output token); slice before the unembed so
     # the launch never materializes [B, Q, V]
     xl = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    return _logits(cfg, params, xl)[:, 0], k_pages, v_pages
+    return (_logits(cfg, params, xl)[:, 0], k_pages, v_pages) + tail
 
 
 # one jitted step per (config, interpret, mesh layout) shared across
@@ -458,6 +499,13 @@ class PagedRealtimeEngine:
         # grid a fixed slots x context-cap tiling would step through
         self.attn_blocks_live = 0
         self.attn_blocks_grid = 0
+        # the held-expert layers' routing, summed over layers and
+        # launches (MoE configs; fetched with each launch's logits):
+        # (token, expert) pairs routed, those landing on experts this
+        # chip holds, and held experts touched
+        self.moe_routed_pairs = 0
+        self.moe_held_pairs = 0
+        self.moe_experts_touched = 0
         # reload pages landed inside a turn-start settlement (on the
         # turn's path) and by transfer drains (off it)
         self.settled_reload_pages = 0
@@ -1612,6 +1660,18 @@ class PagedRealtimeEngine:
             q_start, q_lens, block_span=self._attn_block, nblk=nblk).sum())
         self.attn_blocks_grid += self.slots * nblk
 
+    def _fetch(self, logits, counts: list) -> np.ndarray:
+        """The launch's logits on the host; an MoE step's routing counts
+        come in the same copy and are added to the engine's counters."""
+        if not counts:
+            return np.asarray(logits)
+        logits, (routed, held, touched) = jax.device_get(
+            (logits, counts[0]))
+        self.moe_routed_pairs += int(routed)
+        self.moe_held_pairs += int(held)
+        self.moe_experts_touched += int(touched)
+        return logits
+
     def _run_rows(self, feeds: Dict[int, tuple]) -> Dict[int, np.ndarray]:
         """Run one compiled step with `feeds[row] = (sid, token)`; other
         rows are padded to the scratch page. Returns per-row logits."""
@@ -1632,13 +1692,14 @@ class PagedRealtimeEngine:
                     jnp.asarray(tabs.write_slot))
         with span("serve.round.launch", B=self.slots, Q=1,
                   valid=len(feeds)):
-            logits, self.k_pages, self.v_pages = self._step_fn(*args)
+            logits, self.k_pages, self.v_pages, *counts = \
+                self._step_fn(*args)
         self.launch_valid_tokens += len(feeds)
         self.launch_grid_tokens += self.slots
         self._count_attn_blocks(tabs.seq_lens - 1,
                                 np.ones_like(tabs.seq_lens))
         with span("serve.round.logits"):
-            logits = np.asarray(logits)
+            logits = self._fetch(logits, counts)
         if self.logit_tap is not None:
             for i, (sid, _) in feeds.items():
                 self.logit_tap(sid, logits[i])
@@ -1671,10 +1732,11 @@ class PagedRealtimeEngine:
         with span("serve.round.launch", B=self.slots, Q=q_tokens,
                   valid=valid):
             if self._spec_fn is not None:
-                logits, outs, self.k_pages, self.v_pages = \
+                logits, outs, self.k_pages, self.v_pages, *counts = \
                     self._spec_fn(*args)
             else:
-                logits, self.k_pages, self.v_pages = self._fused_fn(*args)
+                logits, self.k_pages, self.v_pages, *counts = \
+                    self._fused_fn(*args)
         self.fused_launches += 1
         self.launch_valid_tokens += valid
         self.launch_grid_tokens += self.slots * q_tokens
@@ -1684,7 +1746,7 @@ class PagedRealtimeEngine:
             if self._spec_fn is not None:
                 outs = np.asarray(outs)
                 out_rows = {i: outs[i] for i in feeds}
-            logits = np.asarray(logits)
+            logits = self._fetch(logits, counts)
         if self.logit_tap is not None:
             for i, (sid, _) in feeds.items():
                 self.logit_tap(sid, logits[i])

@@ -17,6 +17,12 @@ An operator captures a trace with::
         ...                      # serve for a few seconds
 
 Tracing, like the profiler it feeds, is one switch for the process.
+
+The engine's counters stay on whatever this switch says; for an MoE
+config they include the held-expert layer's routing, summed over its
+layers and fetched with each launch's logits: ``moe_routed_pairs``,
+``moe_held_pairs`` and ``moe_experts_touched`` (DESIGN.md §17). Its
+kernel shows in the device trace as ``moe_experts``.
 """
 from __future__ import annotations
 

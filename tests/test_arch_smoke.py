@@ -15,7 +15,7 @@ from repro.models import (decode_step, forward, init_cache, init_params,
 ARCHS = [
     "whisper-tiny", "h2o-danube-1.8b", "qwen3-4b", "nemotron-4-340b",
     "qwen2-1.5b", "recurrentgemma-9b", "mamba2-1.3b", "deepseek-v2-236b",
-    "phi3.5-moe-42b-a6.6b", "paligemma-3b",
+    "phi3.5-moe-42b-a6.6b", "paligemma-3b", "qwen3-30b-a3b",
 ]
 
 B, S = 2, 24
@@ -51,7 +51,7 @@ def test_config_param_count_positive(arch):
                 "phi3.5-moe-42b-a6.6b": 42e9, "qwen3-4b": 4e9,
                 "qwen2-1.5b": 1.5e9, "h2o-danube-1.8b": 1.8e9,
                 "mamba2-1.3b": 1.3e9, "recurrentgemma-9b": 9e9,
-                "paligemma-3b": 2.6e9}
+                "paligemma-3b": 2.6e9, "qwen3-30b-a3b": 30.5e9}
     if arch in expected:
         assert 0.5 < n / expected[arch] < 2.0, (arch, n)
 

@@ -5,9 +5,10 @@ Mosaic checks (tile alignment, VMEM, layouts), so a kernel can pass
 every CPU test and be refused by the chip's compiler. These tests
 compile the paged attention kernels at qwen2-1.5b's and qwen3-4b's
 attention widths in bf16, at the benchmark's served shapes among
-others, and the fused serving step at qwen2-1.5b's published widths
-with two layers, for a described ``v5e:2x2`` device — no chip time
-needed.
+others, the ``moe_experts`` kernel at Qwen3-30B-A3B's expert widths
+(16 held experts), and the fused serving step at qwen2-1.5b's and at
+that MoE share's published widths with two layers, for a described
+``v5e:2x2`` device — no chip time needed.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and every
@@ -105,18 +106,61 @@ def test_paged_kernels_compile_for_v5e(one_chip, model, slots, pps, page,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tokens", [16, 512])
+def test_moe_experts_compiles_for_v5e(one_chip, tokens):
+    """The held experts' FFN at the served grids: 16 slots of one
+    token (decode) and of a 32-token chunk, 16 of 128 experts held,
+    top-8, d 2,048, expert width 768, bf16."""
+    from repro.kernels.moe_experts import moe_experts, plan
+    d, f, held, k = 2048, 768, 16, 8
+
+    def layer(x, sel, w, valid, wg, wu, wd):
+        p = plan(sel, w, valid, offset=0, held=held)
+        return moe_experts(x, p, wg, wu, wd), p.counts
+
+    pairs = _sds((tokens, k), jnp.int32, one_chip)
+    compiled = jax.jit(layer).lower(
+        _sds((tokens, d), jnp.bfloat16, one_chip), pairs,
+        _sds((tokens, k), jnp.float32, one_chip),
+        _sds((tokens,), jnp.bool_, one_chip),
+        _sds((held, d, f), jnp.bfloat16, one_chip),
+        _sds((held, d, f), jnp.bfloat16, one_chip),
+        _sds((held, f, d), jnp.bfloat16, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _moe_share():
+    """qwen3-30b-a3b as the benchmark serves it: 16 of 128 experts."""
+    import dataclasses
+    cfg = get_config("qwen3-30b-a3b")
+    return cfg.replace(moe=dataclasses.replace(cfg.moe, held_count=16))
+
+
 def test_fused_step_compiles_for_v5e(one_chip, qwen):
     """The whole fused round at published widths (two layers), with the
     kernel compiled for the chip inside it, fits one chip's HBM."""
+    _compile_fused_step(one_chip, qwen.replace(num_layers=2))
+
+
+@pytest.mark.parametrize("Q", [1, 32])
+def test_moe_fused_step_compiles_for_v5e(one_chip, Q):
+    """The same for the MoE share (two layers), with the held-expert
+    layer's kernel inside, at the benchmark's served shapes (16 slots,
+    1,024 pages, 2,048-token context) in a decode and a 32-token chunk
+    round."""
+    _compile_fused_step(one_chip, _moe_share().replace(num_layers=2), Q=Q,
+                        B=16, pps=128, num_pages=1024)
+
+
+def _compile_fused_step(one_chip, cfg, Q=16, B=B, pps=PPS, num_pages=PAGES):
     from repro.models import init_params
     from repro.serving.paged_engine import paged_fused_step
-    cfg = qwen.replace(num_layers=2)
     params = jax.tree.map(
         lambda a: _sds(a.shape, a.dtype, one_chip),
         jax.eval_shape(functools.partial(init_params, cfg),
                        jax.random.PRNGKey(0)))
-    Q, page = 16, 16
-    pages = _sds((cfg.num_layers, PAGES + 1, page,
+    page = 16
+    pages = _sds((cfg.num_layers, num_pages + 1, page,
                   cfg.num_kv_heads * cfg.resolved_head_dim),
                  jnp.dtype(cfg.dtype), one_chip)
     tok = _sds((B, Q), jnp.int32, one_chip)
@@ -124,7 +168,7 @@ def test_fused_step_compiles_for_v5e(one_chip, qwen):
     step = jax.jit(functools.partial(paged_fused_step, cfg,
                                      interpret=False))
     compiled = step.lower(params, tok, tok, pages, pages,
-                          _sds((B, PPS), jnp.int32, one_chip), rows, rows,
+                          _sds((B, pps), jnp.int32, one_chip), rows, rows,
                           tok, tok).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
